@@ -55,7 +55,7 @@ PAYLOAD_BYTES = 256
 TOPICS = ("/camera", "/lidar")
 FRAME_BATCH = 512          # messages per DATA frame (device batch rows)
 REPEATS = 3
-MODEL = "qwen3-4b"
+MODEL = "qwen3-4b-tiny"    # 256 B records are narrower than d_model=2560
 SUITE_MSGS = 1024          # verdict-phase stream (two full model sweeps)
 SUITE_BATCH = 128
 #: CI gate: the zero-copy frame->batch path must beat the per-message
@@ -203,7 +203,7 @@ def run_race() -> dict:
 
     msgs = _make_messages()
     frames = _make_frames(msgs)
-    step = PerceptionStep(model=MODEL, metrics=True)
+    step = PerceptionStep(MODEL, metrics=True)
 
     # bit-parity verification first (untimed; also warms the jit trace):
     # three consumers, one digest algebra, identical folds

@@ -57,6 +57,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     from benchmarks import (aggregation, bag_cache, binpipe, chaos,
                             perception, pipeline, roofline_report,

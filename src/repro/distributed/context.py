@@ -63,19 +63,7 @@ def dp() -> tuple:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map``: newer jax exposes it at the top
-    level (with replication checking behind ``check_vma``), older ships it
-    in ``jax.experimental`` (as ``check_rep``).  Checking is disabled on
-    both paths — callers here do manual collectives the checker can't
-    type."""
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        try:
-            return top(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-        except TypeError:       # top-level API without check_vma
-            return top(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication checking off: callers here do
+    manual collectives the checker can't type."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

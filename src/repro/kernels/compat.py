@@ -1,8 +1,4 @@
-"""Pallas API compatibility shims + backend-mode resolution.
-
-``pltpu.TPUCompilerParams`` was renamed to ``pltpu.CompilerParams`` across
-jax releases; the kernels import the resolved name from here so they run on
-either side of the rename.
+"""Backend-mode resolution for the Pallas kernels.
 
 :func:`resolve_interpret` is the single policy point for Pallas interpret
 mode.  Every kernel entry point (``sensor_decode*``, ``flash_attention``,
@@ -24,11 +20,6 @@ from __future__ import annotations
 
 import os
 from typing import Optional
-
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
 
 #: environment toggle honored by every kernel entry point
 INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
@@ -56,4 +47,4 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     return jax.default_backend() != "tpu"
 
 
-__all__ = ["CompilerParams", "INTERPRET_ENV", "resolve_interpret"]
+__all__ = ["INTERPRET_ENV", "resolve_interpret"]

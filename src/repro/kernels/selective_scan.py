@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams, resolve_interpret
+from .compat import resolve_interpret
 
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, h_scr, *,
@@ -96,7 +96,7 @@ def _selective_scan(x: jax.Array, dt: jax.Array, B: jax.Array, C: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bsz, ns * blk_s, nd * blk_d),
                                        jnp.float32),
         scratch_shapes=[pltpu.VMEM((blk_d, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, B, C, A)

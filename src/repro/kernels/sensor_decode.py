@@ -24,13 +24,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams, resolve_interpret
+from .compat import resolve_interpret
 
 
 def _decode_kernel(payload_ref, scale_ref, zp_ref, len_ref, out_ref, *,
                    blk_n: int):
     j = pl.program_id(1)
-    u = payload_ref[...].astype(jnp.float32)            # (blk_r, blk_n)
+    # Mosaic has no direct uint8 -> float32 cast; widen through int32
+    u = payload_ref[...].astype(jnp.int32).astype(jnp.float32)
     scale = scale_ref[...].astype(jnp.float32)          # (blk_r, 1)
     zp = zp_ref[...].astype(jnp.float32)                # (blk_r, 1)
     ln = len_ref[...]                                   # (blk_r, 1) int32
@@ -84,7 +85,7 @@ def _sensor_decode(payload: jax.Array, scale: jax.Array,
         ],
         out_specs=pl.BlockSpec((blk_r, blk_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nr * blk_r, nn * blk_n), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(payload, scale[:, None], zero_point[:, None],
@@ -109,7 +110,8 @@ def _decode_metrics_kernel(payload_ref, scale_ref, zp_ref, len_ref, ts_ref,
     j = pl.program_id(1)
     nj = pl.num_programs(1)
     u8 = payload_ref[...]                               # (blk_r, blk_n)
-    u = u8.astype(jnp.float32)
+    b32 = u8.astype(jnp.int32)
+    u = b32.astype(jnp.float32)
     scale = scale_ref[...].astype(jnp.float32)          # (blk_r, 1)
     zp = zp_ref[...].astype(jnp.float32)                # (blk_r, 1)
     ln = len_ref[...]                                   # (blk_r, 1) int32
@@ -118,13 +120,16 @@ def _decode_metrics_kernel(payload_ref, scale_ref, zp_ref, len_ref, ts_ref,
     mask = col < ln
     out_ref[...] = jnp.where(mask, (u - zp) * scale, 0.0)
 
-    # per-record reduction partials over this byte block
+    # per-record reduction partials over this byte block.  Mosaic has no
+    # unsigned reductions: the wrapping sum runs in int32 (same bits) and
+    # is bitcast back
     w = (col.astype(jnp.uint32) * jnp.uint32(2246822519)
          + jnp.uint32(0x9E3779B9))
-    part = jnp.sum(jnp.where(mask, u8.astype(jnp.uint32) * w, 0),
-                   axis=1, keepdims=True, dtype=jnp.uint32)
+    prod = jnp.where(mask, b32.astype(jnp.uint32) * w, jnp.uint32(0))
+    part = jax.lax.bitcast_convert_type(
+        jnp.sum(jax.lax.bitcast_convert_type(prod, jnp.int32), axis=1,
+                keepdims=True, dtype=jnp.int32), jnp.uint32)
     cnt = jnp.sum(mask, axis=1, keepdims=True, dtype=jnp.int32)
-    b32 = u8.astype(jnp.int32)
     mn = jnp.min(jnp.where(mask, b32, 256), axis=1, keepdims=True)
     mx = jnp.max(jnp.where(mask, b32, -1), axis=1, keepdims=True)
 
@@ -225,7 +230,7 @@ def _sensor_decode_metrics(payload: jax.Array, scale: jax.Array,
             jax.ShapeDtypeStruct((nr * blk_r, 1), jnp.int32),
             jax.ShapeDtypeStruct((nr * blk_r, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(payload, scale[:, None], zero_point[:, None],

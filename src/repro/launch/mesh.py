@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -20,7 +20,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     axis — see distributed/pipeline.py)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model code places activations with
+    # with_sharding_constraint, which rejects jax.make_mesh's default
+    # Explicit axes
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 2, model: int = 2, pod: int = 0) -> Mesh:

@@ -23,10 +23,13 @@ frame to model logits performs no per-message work at all.
 Scenario integration: ``user_logic="perception://<model>"`` resolves (via
 ``resolve_logic_ref``) to a cached :class:`PerceptionStep` and runs it as
 a first-class *batched* logic — no custom callables.  ``<model>`` is any
-registered arch name (``qwen3-4b``, ``falcon-mamba-7b``, ...), reduced to
-its tiny same-structure config so CPU suites stay cheap; params are
-deterministic in ``seed``, so two steps built from the same ref are
-bit-identical — golden verdicts are stable across runs and processes.
+registered arch name (``qwen3-4b``, ``falcon-mamba-7b``, ...), built at its
+published widths, or that name with a ``-tiny`` suffix (``qwen3-4b-tiny``)
+for its reduced same-structure config (:func:`repro.configs.tiny
+.tiny_config`), which keeps CPU suites cheap.  Params are initialised on
+the device under one jit, in the config's dtype, and are deterministic in
+``seed``, so two steps built from the same ref are bit-identical — golden
+verdicts are stable across runs and processes.
 
 Thread backends only: the step owns jitted state, and process-backend
 workers fork from a jax-loaded driver (initialising jax there can
@@ -47,17 +50,92 @@ from repro.obs import trace as otrace
 #: default topic perception outputs publish on
 OUT_TOPIC = "/perception"
 
+#: model-name suffix selecting an arch's reduced same-structure config
+TINY_SUFFIX = "-tiny"
+
 
 def _ts_low(timestamps: np.ndarray) -> np.ndarray:
     return (np.asarray(timestamps).astype(np.uint64)
             & np.uint64(0xFFFFFFFF)).astype(np.uint32)
 
 
+def resolve_config(model: str):
+    """The config a model name names: ``<arch>-tiny`` is the reduced
+    same-structure config of ``<arch>``, any other name the registered
+    config at its published widths."""
+    from repro.configs.tiny import tiny_config
+    from repro.models import get_config
+    if model.endswith(TINY_SUFFIX):
+        return tiny_config(model[:-len(TINY_SUFFIX)])
+    return get_config(model)
+
+
+def init_params(cfg, seed: int):
+    """Deterministic params for ``cfg``, made on the default device under
+    one jit in the config's dtype, so no float32 copy of a bf16 model is
+    ever materialised on the host."""
+    import jax
+    from repro.models import get_model
+    return jax.jit(get_model(cfg).init_params)(jax.random.PRNGKey(seed))
+
+
+def features_to_logits(cfg, params, feats, out_features: int):
+    """Model head over decoded features: (R, Nb) f32 -> (R, out_features)
+    f32.  Rows are cut into ``Nb // d_model`` embedding tokens, run through
+    the model forward, and the last position's first ``out_features``
+    logits are kept.  Also the reference face: the same forward over
+    features decoded off the Pallas path."""
+    import jax.numpy as jnp
+    from repro.models import get_model
+    d_model = cfg.d_model
+    R, Nb = feats.shape
+    S = Nb // d_model
+    if S == 0:
+        raise ValueError(
+            f"payload rows of {Nb} bytes are narrower than "
+            f"d_model={d_model}; pad records to at least one token")
+    embeds = feats[:, :S * d_model].reshape(R, S, d_model)
+    logits = get_model(cfg).forward(params, {"embeds": embeds})
+    return logits[:, -1, :out_features].astype(jnp.float32)
+
+
+def build_step(cfg, *, out_features: int, metrics: bool, donate: bool,
+               interpret: bool):
+    """The jitted decode→forward program a :class:`PerceptionStep` runs:
+    ``step(params, payload, scale, zero_point, lengths[, ts_low]) ->
+    (logits, record_digests | None)``.  Module-level so it can be lowered
+    against ``jax.eval_shape`` params without initialising a model."""
+    import jax
+    from repro.kernels.sensor_decode import (sensor_decode,
+                                            sensor_decode_metrics)
+
+    if metrics:
+        def step(params, payload, scale, zero_point, lengths, ts_low):
+            out = sensor_decode_metrics(payload, scale, zero_point,
+                                        lengths, ts_low,
+                                        interpret=interpret)
+            return (features_to_logits(cfg, params, out["features"],
+                                       out_features),
+                    out["record_digests"])
+        donate_argnums = (1, 2, 3, 4, 5)
+    else:
+        def step(params, payload, scale, zero_point, lengths):
+            feats = sensor_decode(payload, scale, zero_point, lengths,
+                                  interpret=interpret)
+            return features_to_logits(cfg, params, feats, out_features), None
+        donate_argnums = (1, 2, 3, 4)
+    # params (arg 0) are NOT donated — they persist across steps; the
+    # batch buffers are consumed exactly once, which is what makes
+    # them donatable
+    return jax.jit(step, donate_argnums=donate_argnums if donate else ())
+
+
 class PerceptionStep:
     """Jitted decode→forward consumer with a donated steady-state loop.
 
-    ``model`` — registered arch name; the tiny same-structure config is
-    used (attention archs exercise ``models/transformer.py``, SSM archs
+    ``model`` — registered arch name at its published widths, or
+    ``<arch>-tiny`` for the reduced config (see :func:`resolve_config`;
+    attention archs exercise ``models/transformer.py``, SSM archs
     ``models/ssm.py`` through the same forward).  ``metrics=True`` swaps
     the decode for the fused ``sensor_decode_metrics`` sweep, so the step
     also returns per-record input digests (the aggregation checksums) for
@@ -72,15 +150,11 @@ class PerceptionStep:
     zero-copy face (columnar batch dict in, columnar batch dict out).
     """
 
-    def __init__(self, model: str = "qwen3-4b", seed: int = 0,
+    def __init__(self, model: str, seed: int = 0,
                  out_topic: str = OUT_TOPIC, out_features: int = 16,
                  metrics: bool = False, donate: bool = True,
                  interpret: Optional[bool] = None):
-        import jax
-        from repro.configs.tiny import tiny_config
-        from repro.models import get_model
-
-        cfg = tiny_config(model)
+        cfg = resolve_config(model)
         if out_features < 1 or out_features > cfg.vocab_size:
             raise ValueError(f"out_features must be in [1, {cfg.vocab_size}]")
         self.model = model
@@ -91,47 +165,10 @@ class PerceptionStep:
         self.donate = donate
         self.interpret = resolve_interpret(interpret)
         self.cfg = cfg
-        api = get_model(cfg)
-        self.params = api.init_params(jax.random.PRNGKey(seed))
-        self._step = self._build(api.forward)
-
-    def _build(self, forward):
-        import jax
-        import jax.numpy as jnp
-        from repro.kernels.sensor_decode import (sensor_decode,
-                                                sensor_decode_metrics)
-        d_model = self.cfg.d_model
-        out_k = self.out_features
-        interpret = self.interpret
-
-        def head(params, feats):
-            R, Nb = feats.shape
-            S = Nb // d_model
-            if S == 0:
-                raise ValueError(
-                    f"payload rows of {Nb} bytes are narrower than "
-                    f"d_model={d_model}; pad records to at least one token")
-            embeds = feats[:, :S * d_model].reshape(R, S, d_model)
-            logits = forward(params, {"embeds": embeds})
-            return logits[:, -1, :out_k].astype(jnp.float32)
-
-        if self.metrics:
-            def step(params, payload, scale, zero_point, lengths, ts_low):
-                out = sensor_decode_metrics(payload, scale, zero_point,
-                                            lengths, ts_low,
-                                            interpret=interpret)
-                return head(params, out["features"]), out["record_digests"]
-            donate = (1, 2, 3, 4, 5)
-        else:
-            def step(params, payload, scale, zero_point, lengths):
-                feats = sensor_decode(payload, scale, zero_point, lengths,
-                                      interpret=interpret)
-                return head(params, feats), None
-            donate = (1, 2, 3, 4)
-        # params (arg 0) are NOT donated — they persist across steps; the
-        # batch buffers are consumed exactly once, which is what makes
-        # them donatable
-        return jax.jit(step, donate_argnums=donate if self.donate else ())
+        self.params = init_params(cfg, seed)
+        self._step = build_step(cfg, out_features=out_features,
+                                metrics=metrics, donate=donate,
+                                interpret=self.interpret)
 
     # -- array faces --------------------------------------------------------
 
@@ -222,4 +259,5 @@ def get_step(ref: str) -> PerceptionStep:
     return step
 
 
-__all__ = ["OUT_TOPIC", "PerceptionStep", "SCHEME", "get_step"]
+__all__ = ["OUT_TOPIC", "PerceptionStep", "SCHEME", "build_step",
+           "features_to_logits", "get_step", "init_params", "resolve_config"]

@@ -21,6 +21,8 @@ from repro.net.wire import (WireError, batch_to_frame, decode_data,
                             encode_data, frame_to_batch)
 
 TOPICS = ("/camera", "/lidar")
+#: CPU-sized perception model: the reduced same-structure qwen3-4b config
+TINY = "qwen3-4b-tiny"
 
 
 def _msgs(n=100, payload=256, seed=0, topics=TOPICS):
@@ -153,7 +155,7 @@ def test_perception_step_message_vs_zero_copy_parity():
     from repro.perception import PerceptionStep
 
     msgs = _msgs(24, payload=256, seed=4)
-    step = PerceptionStep(metrics=True, donate=False)
+    step = PerceptionStep(TINY, metrics=True, donate=False)
     out = step.run_batch(frame_to_batch(encode_data(msgs)))
     via_msgs = step(msgs)
     assert [t for t, _, _ in via_msgs] == [step.out_topic] * len(msgs)
@@ -166,7 +168,7 @@ def test_perception_step_message_vs_zero_copy_parity():
                                _ts_low(batch["timestamps"]))
     assert np.array_equal(out["input_record_digests"], expect)
     # deterministic in (model, seed): a fresh step reproduces the bytes
-    again = PerceptionStep(metrics=True, donate=False)
+    again = PerceptionStep(TINY, metrics=True, donate=False)
     out2 = again.run_batch(frame_to_batch(encode_data(msgs)))
     assert np.array_equal(out2["payload"], out["payload"])
 
@@ -175,7 +177,7 @@ def test_perception_step_output_batch_feeds_wire_and_metrics():
     from repro.perception import PerceptionStep
 
     msgs = _msgs(16, payload=128, seed=5)
-    step = PerceptionStep(donate=False)
+    step = PerceptionStep(TINY, donate=False)
     out = step.run_batch(frame_to_batch(encode_data(msgs)))
     assert out["payload"].shape == (16, 4 * step.out_features)
     assert out["topics"] == (step.out_topic,)
@@ -209,7 +211,7 @@ def test_perception_step_donates_and_is_silent():
 
     # the step donates its device-side batch copies, never the caller's
     # numpy batch: the frame view must be readable after the call
-    donating = PerceptionStep(donate=True)
+    donating = PerceptionStep(TINY, donate=True)
     msgs = _msgs(8, payload=128, seed=6)
     batch = frame_to_batch(encode_data(msgs))
     before = batch["payload"].copy()
@@ -222,7 +224,7 @@ def test_perception_step_donates_and_is_silent():
     assert np.asarray(logits).shape == (8, donating.out_features)
 
     # donate=False keeps even device-side inputs alive
-    step = PerceptionStep(donate=False)
+    step = PerceptionStep(TINY, donate=False)
     kept = jnp.zeros((8, 128), jnp.uint8)
     step._step(step.params, kept, jnp.full(8, 1 / 255, jnp.float32),
                jnp.zeros(8, jnp.float32), jnp.full(8, 128, jnp.int32))
@@ -245,13 +247,13 @@ def test_perception_scheme_runs_as_batched_logic(tmp_path):
     from repro.perception import get_step
 
     bag_path = _perception_bag(tmp_path)
-    sc = Scenario("perc", bag_path, "perception://qwen3-4b",
+    sc = Scenario("perc", bag_path, "perception://" + TINY,
                   batch_size=16, num_partitions=1)
     a = ScenarioSuite([sc], num_workers=1).run(timeout=300)["perc"]
     b = ScenarioSuite([sc], num_workers=1).run(timeout=300)["perc"]
     assert a.passed and not a.vacuous
     assert a.report.messages_out == 64
-    assert list(a.metrics) == [get_step("perception://qwen3-4b").out_topic]
+    assert list(a.metrics) == [get_step("perception://" + TINY).out_topic]
     # jitted replay is deterministic: bit-identical output images
     assert a.report.output_image == b.report.output_image
 
@@ -259,10 +261,51 @@ def test_perception_scheme_runs_as_batched_logic(tmp_path):
 def test_perception_scheme_requires_batch_size_and_thread_backend(tmp_path):
     bag_path = _perception_bag(tmp_path, n=8)
     with pytest.raises(ValueError, match="batch_size"):
-        Scenario("perc", bag_path, "perception://qwen3-4b")
-    sc = Scenario("perc", bag_path, "perception://qwen3-4b", batch_size=8)
+        Scenario("perc", bag_path, "perception://" + TINY)
+    sc = Scenario("perc", bag_path, "perception://" + TINY, batch_size=8)
     with pytest.raises(ValueError, match="thread backend"):
         ScenarioSuite([sc], backend="process").run(timeout=60)
+
+
+# -- model-name resolution ---------------------------------------------------
+
+
+def test_perception_tiny_name_builds_tiny_config():
+    from repro.configs.tiny import tiny_config
+    from repro.perception import get_step
+
+    step = get_step("perception://" + TINY)
+    assert step.cfg == tiny_config("qwen3-4b")
+    assert step.cfg.name == TINY
+    assert step.cfg.d_model == 64 and step.cfg.num_layers == 2
+
+
+def test_perception_published_name_resolves_full_width():
+    """``perception://qwen3-4b`` names the published widths.  Checked on
+    the config and on shapes only: nothing is initialised."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import get_model
+    from repro.perception import build_step, resolve_config
+
+    cfg = resolve_config("qwen3-4b")
+    assert (cfg.name, cfg.d_model, cfg.num_layers) == ("qwen3-4b", 2560, 36)
+    assert cfg.dtype == "bfloat16"
+    params = jax.eval_shape(get_model(cfg).init_params,
+                            jax.random.PRNGKey(0))
+    leaves = jax.tree.leaves(params)
+    assert {p.dtype for p in leaves} == {jnp.dtype(jnp.bfloat16)}
+    assert 4.0e9 < sum(p.size for p in leaves) < 4.5e9
+    # one 32-token row of 81,920 bytes through the step, abstractly
+    step = build_step(cfg, out_features=16, metrics=True, donate=False,
+                      interpret=True)
+    R, Nb = 2, 32 * cfg.d_model
+    logits, digests = jax.eval_shape(
+        step, params, jax.ShapeDtypeStruct((R, Nb), jnp.uint8),
+        *(jax.ShapeDtypeStruct((R,), dt) for dt in
+          (jnp.float32, jnp.float32, jnp.int32, jnp.uint32)))
+    assert (logits.shape, logits.dtype) == ((R, 16), jnp.float32)
+    assert (digests.shape, digests.dtype) == ((R,), jnp.uint32)
 
 
 # -- REPRO_PALLAS_INTERPRET plumbing -----------------------------------------
