@@ -775,8 +775,8 @@ class RosPlay:
             it_ = iter(it)
             while True:
                 # traced at batch granularity: ``play.read`` bills framing
-                # (bag read + decode + heap ordering), ``play.publish``
-                # bills bus dispatch — the two halves of the replay stage
+                # (bag read + decode + heap ordering); what the publish
+                # runs downstream bills its own spans
                 if tr is not None:
                     r_slot = tr.begin("play.read", "play")
                     batch = next(it_, None)
@@ -792,13 +792,7 @@ class RosPlay:
                     delay = target - (time.monotonic() - t0_wall)
                     if delay > 0:
                         time.sleep(delay)
-                if tr is not None:
-                    p_slot = tr.begin("play.publish", "play",
-                                      attrs={"n": len(batch)})
-                    self.messages_played += self._bus.publish_batch(batch)
-                    otrace.Tracer.end(p_slot)
-                else:
-                    self.messages_played += self._bus.publish_batch(batch)
+                self.messages_played += self._bus.publish_batch(batch)
         finally:
             close = getattr(it, "close", None)
             if close is not None:       # stop an abandoned reader thread

@@ -417,13 +417,19 @@ def _run_scenario_partition(scenario: Scenario, source: "str | bytes",
         src = Bag.open_read(source, backend="disk")
     if scenario.use_memory_cache:
         # materialise the (filtered) partition into the ROSBag cache (§3.2):
+        tr = otrace.TRACER
+        slot = tr.begin("bag.cache_fill", "play") if tr is not None else None
         cache = Bag.open_write(backend="memory")
         for msg in src.read_messages(topics=topics, start=t_start,
                                      end=t_end, chunk_range=chunk_range):
             cache.write_message(msg)
         cache.close()
-        play_bag = Bag.open_read(backend="memory",
-                                 image=cache.chunked_file.image())
+        cache_image = cache.chunked_file.image()
+        play_bag = Bag.open_read(backend="memory", image=cache_image)
+        if slot is not None:
+            otrace.Tracer.set_attrs(slot, {"messages": play_bag.num_messages,
+                                           "bytes": len(cache_image)})
+            otrace.Tracer.end(slot)
         play = dict(chunk_range=None, topics=None, start=None, end=None)
         input_topics = play_bag.topics
     else:
@@ -527,8 +533,11 @@ def _run_scenario_partition(scenario: Scenario, source: "str | bytes",
         def on_batch(msgs: list[Message]) -> None:
             nonlocal n_out, n_drop
             tr = otrace.TRACER
-            slot = (tr.begin("logic.step", "logic", attrs={"n": len(msgs)})
-                    if tr is not None else None)
+            slot = None
+            if tr is not None:
+                # the step's own spans (perception.step, .readback) nest
+                slot = tr.begin("logic.step", "logic", attrs={"n": len(msgs)})
+                tr.push(otrace.Tracer.span_id(slot))
             try:
                 if drop:
                     kept = [m for m in msgs if rng.random() >= drop]
@@ -549,6 +558,7 @@ def _run_scenario_partition(scenario: Scenario, source: "str | bytes",
                     n_out += len(out_msgs)
             finally:
                 if slot is not None:
+                    tr.pop()
                     otrace.Tracer.end(slot)
 
         for t in input_topics:
@@ -580,6 +590,7 @@ def _run_scenario_partition(scenario: Scenario, source: "str | bytes",
 
     rec.start()
     player = RosPlay(play_bag, bus, **play)
+    close_slot = None
     try:
         if scenario.batch_size is None:
             n_in = player.run(prefetch=MESSAGE_PREFETCH if staged else 0)
@@ -588,6 +599,11 @@ def _run_scenario_partition(scenario: Scenario, source: "str | bytes",
             # the next micro-batch decoded while this one is in flight
             n_in = player.run_batched(scenario.batch_size,
                                       prefetch=2 if staged else 0)
+        # the partition's end: stage barriers, recorder stop, output image
+        # and metric partials
+        tr = otrace.TRACER
+        close_slot = (tr.begin("partition.close", "record")
+                      if tr is not None else None)
         bus.drain()         # barrier: every stage flushed, errors surface
         _flush_logic(time.perf_counter_ns())    # close the last logic chunk
         if bridge is not None:
@@ -613,7 +629,10 @@ def _run_scenario_partition(scenario: Scenario, source: "str | bytes",
     # use-after-close here was a latent bug before MemoryChunkedFile.close
     # consolidated the image
     image = out_bag.chunked_file.image()
-    return n_in, n_out, n_drop, image, tap.finalize(), exported
+    partials = tap.finalize()
+    if close_slot is not None:
+        otrace.Tracer.end(close_slot)
+    return n_in, n_out, n_drop, image, partials, exported
 
 
 def _run_scenario_aggregate(aggregator: Aggregator, scenario_name: str,

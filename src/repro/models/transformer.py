@@ -157,10 +157,15 @@ def block_forward(cfg: ModelConfig, p: dict, x: jax.Array, positions,
     # pin the remat-saved layer input to bf16: without the barrier XLA
     # hoists the norm's f32 upcast into the saved stack (3x the memory)
     x = _remat_barrier(x)
-    mix, caches = _mix_forward(cfg, p, x, positions)
+    # named scopes reach every compiled op's ``op_name`` metadata, which
+    # is how a device trace's ops are put on layers; a hybrid's mixer
+    # counts as attention
+    with jax.named_scope("attention" if cfg.has_attention else "ssm"):
+        mix, caches = _mix_forward(cfg, p, x, positions)
     x = x + mix
     if cfg.d_ff > 0:
-        x = x + _ffn_forward(cfg, p, x)
+        with jax.named_scope("mlp"):
+            x = x + _ffn_forward(cfg, p, x)
     return x, caches
 
 
@@ -171,6 +176,14 @@ def block_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict,
     if cfg.d_ff > 0:
         x = x + _ffn_forward(cfg, p, x)
     return x, new_cache
+
+
+def _head(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
+    """Final norm and the vocabulary projection, under the ``head``
+    scope."""
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["embed"]["final_norm"], cfg.norm_eps)
+        return lm_logits(params["embed"], x, cfg.tie_embeddings)
 
 
 # --------------------------------------------------------------------------
@@ -256,8 +269,7 @@ def forward(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
     x, positions = _embed_inputs(cfg, params, batch)
     x, _ = _run_layers(cfg, params, x, positions)
     x = ctx.constrain(x, ctx.dp(), None, None)
-    x = rms_norm(x, params["embed"]["final_norm"], cfg.norm_eps)
-    return lm_logits(params["embed"], x, cfg.tie_embeddings)
+    return _head(cfg, params, x)
 
 
 def loss_fn(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
@@ -401,6 +413,5 @@ def decode_step(cfg: ModelConfig, params: dict, state: DecodeState,
             x, nc = block_decode(cfg, lp, x, c, index)
             new_list.append(nc)
         new_caches = new_list
-    x = rms_norm(x, params["embed"]["final_norm"], cfg.norm_eps)
-    logits = lm_logits(params["embed"], x, cfg.tie_embeddings)
+    logits = _head(cfg, params, x)
     return DecodeState(new_caches, index + 1, logits)

@@ -19,6 +19,9 @@ counters scattered across classes.  This package gives them one home:
   with one process-wide registry, so a suite-level ``snapshot()`` can
   be persisted into the verdict manifest.
 
+* :mod:`repro.obs.compiles` — the process's one JAX compile listener,
+  behind the ``jit`` metrics scope and the ``jax.compile`` span.
+
 * :mod:`repro.obs.export` — Chrome/Perfetto ``trace.json`` writer
   (load the file at https://ui.perfetto.dev) consumed by the
   ``repro.tools.trace_report`` critical-path CLI.
@@ -30,12 +33,12 @@ tracer directly for custom harnesses.
 
 from __future__ import annotations
 
-from . import export, metrics, trace
+from . import compiles, export, metrics, trace
 from .metrics import Counter, Gauge, Histogram, Registry, Scope
 from .trace import Tracer, disable, enable, enabled, get_tracer, span
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "Scope", "Tracer",
-    "disable", "enable", "enabled", "export", "get_tracer", "metrics",
-    "span", "trace",
+    "compiles", "disable", "enable", "enabled", "export", "get_tracer",
+    "metrics", "span", "trace",
 ]

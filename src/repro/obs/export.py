@@ -20,19 +20,17 @@ __all__ = ["STAGES", "events_to_records", "stage_breakdown", "to_events",
            "write_trace"]
 
 #: the pipeline-stage taxonomy ``stage_breakdown`` bills spans against
-STAGES = ("read", "decode", "logic", "record", "transport", "cache",
-          "aggregate")
+STAGES = ("read", "logic", "record", "transport", "cache", "aggregate")
 
-_CAT_STAGE = {"play": "read", "record": "record", "transport": "transport",
-              "shm": "transport", "cache": "cache", "agg": "aggregate"}
+_CAT_STAGE = {"play": "read", "logic": "logic", "record": "record",
+              "transport": "transport", "shm": "transport", "cache": "cache",
+              "agg": "aggregate"}
 
 
 def _stage_of(name: str, cat: str, attrs: Optional[dict]) -> Optional[str]:
     """Map one span to the pipeline stage it bills.  ``sched`` / ``suite``
-    spans are containers (queue wait + execution) and bill nothing."""
-    if cat == "logic":
-        # perception.step is the jitted decode→forward program
-        return "decode" if name.startswith("perception.") else "logic"
+    spans are containers (queue wait + execution) and bill nothing, nor
+    does ``jit`` (compiles)."""
     if cat == "lane":
         # lane spans bill the stage their consumer implements
         lane = str((attrs or {}).get("lane", ""))

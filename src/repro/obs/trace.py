@@ -25,7 +25,12 @@ Record layout (one slot / one drained tuple)::
 
 ``cat`` is the seam taxonomy used by ``repro.tools.trace_report``:
 ``sched`` / ``lane`` / ``play`` / ``logic`` / ``record`` /
-``transport`` / ``shm`` / ``cache`` / ``agg`` / ``suite``.
+``transport`` / ``shm`` / ``cache`` / ``agg`` / ``jit`` / ``suite``.
+
+A device profile keeps its own clock.  :meth:`Tracer.anchor` ties the
+two: it records ``perf_counter_ns`` inside a profiler annotation named
+:data:`ANCHOR`, so the annotation's start in the profile minus the
+``obs.anchor`` span's ``t0`` is the offset between the clocks.
 
 Worker processes never export: :func:`task_begin` / :func:`task_end`
 bracket one task, and ``task_end`` drains the local rings so the
@@ -42,8 +47,8 @@ from time import perf_counter_ns
 from typing import Iterable, List, Optional, Tuple
 
 __all__ = [
-    "TRACER", "SpanRecord", "Tracer", "disable", "enable", "enabled",
-    "get_tracer", "ingest", "span", "task_begin", "task_end",
+    "ANCHOR", "TRACER", "SpanRecord", "Tracer", "disable", "enable",
+    "enabled", "get_tracer", "ingest", "span", "task_begin", "task_end",
 ]
 
 #: drained/normalised span tuple (see module docstring)
@@ -54,6 +59,9 @@ _ID, _PARENT, _NAME, _CAT, _T0, _T1, _ATTRS = range(7)
 
 #: per-thread ring capacity (slots); a slot is ~200 B of list + refs
 DEFAULT_CAPACITY = 1 << 14
+
+#: the device profile's host annotation that :meth:`Tracer.anchor` opens
+ANCHOR = "repro.obs.anchor"
 
 
 class _Buf:
@@ -168,6 +176,24 @@ class Tracer:
         slot[_T0] = t0
         slot[_T1] = t1
         return slot[_ID]
+
+    def anchor(self) -> int:
+        """Tie this tracer's clock to a running device profile.
+
+        Opens ``jax.profiler.TraceAnnotation(ANCHOR)``, reads
+        ``perf_counter_ns`` inside it and records the reading as the
+        instant span ``obs.anchor``; returns the reading.  The
+        annotation's start in the profile minus the instant's ``t0`` is
+        the offset from this clock to the profile's.  Whoever starts a
+        device profile anchors right after starting it and right before
+        stopping it: the two offsets bound the drift between the clocks.
+        JAX is imported here, so processes that never anchor never load
+        it."""
+        import jax
+        with jax.profiler.TraceAnnotation(ANCHOR):
+            t = perf_counter_ns()
+        self.emit("obs.anchor", "suite", t, t)
+        return t
 
     # -- ambient context -----------------------------------------------------
 

@@ -39,6 +39,7 @@ deadlock) — ``ScenarioSuite`` rejects the combination up front.
 from __future__ import annotations
 
 import warnings
+from contextlib import contextmanager
 from typing import Optional, Sequence
 
 import numpy as np
@@ -46,6 +47,7 @@ import numpy as np
 from repro.core.bag import Message
 from repro.kernels.compat import resolve_interpret
 from repro.obs import trace as otrace
+from repro.obs.compiles import watch_compiles
 
 #: default topic perception outputs publish on
 OUT_TOPIC = "/perception"
@@ -130,6 +132,18 @@ def build_step(cfg, *, out_features: int, metrics: bool, donate: bool,
     return jax.jit(step, donate_argnums=donate_argnums if donate else ())
 
 
+@contextmanager
+def _donation_quiet():
+    """The logits output is smaller than the donated payload buffer, so
+    backends that only alias shape-matched pairs report the donation as
+    "not usable" — the early-free half of donation still applies, and the
+    warning would fire once per trace."""
+    with warnings.catch_warnings():
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        yield
+
+
 class PerceptionStep:
     """Jitted decode→forward consumer with a donated steady-state loop.
 
@@ -143,14 +157,15 @@ class PerceptionStep:
     :func:`repro.kernels.compat.resolve_interpret` — env
     ``REPRO_PALLAS_INTERPRET``, else compiled on TPU.  ``donate=False``
     opts out of buffer donation (keeps inputs readable after the call —
-    for tests and debugging).
+    for tests and debugging).  ``seed=None`` makes no weights: the step
+    can then be compiled (:meth:`hlo_text`) but not run.
 
     Callable as the batched user-logic contract
     (``list[Message] -> [(topic, ts, bytes)]``); :meth:`run_batch` is the
     zero-copy face (columnar batch dict in, columnar batch dict out).
     """
 
-    def __init__(self, model: str, seed: int = 0,
+    def __init__(self, model: str, seed: Optional[int] = 0,
                  out_topic: str = OUT_TOPIC, out_features: int = 16,
                  metrics: bool = False, donate: bool = True,
                  interpret: Optional[bool] = None):
@@ -165,10 +180,28 @@ class PerceptionStep:
         self.donate = donate
         self.interpret = resolve_interpret(interpret)
         self.cfg = cfg
-        self.params = init_params(cfg, seed)
+        watch_compiles()
+        self.params = None if seed is None else init_params(cfg, seed)
         self._step = build_step(cfg, out_features=out_features,
                                 metrics=metrics, donate=donate,
                                 interpret=self.interpret)
+
+    def hlo_text(self, rows: int, row_bytes: int) -> str:
+        """Optimized HLO text of the program this step runs on batches of
+        ``rows`` records of ``row_bytes`` bytes, compiled from shapes alone
+        (a cache hit where the process or the persistent compilation cache
+        holds that shape).  Each instruction's ``op_name`` carries the
+        model's named scopes (``attention``, ``mlp``, ``head``)."""
+        import jax
+        import jax.numpy as jnp
+        params = jax.eval_shape(lambda: init_params(self.cfg, 0))
+        dtypes = [jnp.float32, jnp.float32, jnp.int32]
+        if self.metrics:
+            dtypes.append(jnp.uint32)
+        args = [jax.ShapeDtypeStruct((rows, row_bytes), jnp.uint8)] + [
+            jax.ShapeDtypeStruct((rows,), d) for d in dtypes]
+        with _donation_quiet():
+            return self._step.lower(params, *args).compile().as_text()
 
     # -- array faces --------------------------------------------------------
 
@@ -184,20 +217,15 @@ class PerceptionStep:
         import jax.numpy as jnp
         tr = otrace.TRACER
         slot = (tr.begin("perception.step", "logic",
-                         attrs={"rows": len(batch["lengths"])})
+                         attrs={"rows": len(batch["lengths"]),
+                                "row_bytes": batch["payload"].shape[1]})
                 if tr is not None else None)
         args = [jnp.array(batch["payload"]), jnp.array(batch["scale"]),
                 jnp.array(batch["zero_point"]),
                 jnp.array(np.asarray(batch["lengths"], dtype=np.int32))]
         if self.metrics:
             args.append(jnp.array(_ts_low(batch["timestamps"])))
-        with warnings.catch_warnings():
-            # the logits output is smaller than the donated payload buffer,
-            # so backends that only alias shape-matched pairs report the
-            # donation as "not usable" — the early-free half of donation
-            # still applies, and the warning would fire once per trace
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable")
+        with _donation_quiet():
             out = self._step(self.params, *args)
         if slot is not None:
             otrace.Tracer.end(slot)
@@ -214,7 +242,15 @@ class PerceptionStep:
         without ever materialising ``Message`` objects.
         """
         logits, digests = self.step_arrays(batch)
+        tr = otrace.TRACER
+        slot = (tr.begin("perception.readback", "logic",
+                         attrs={"rows": logits.shape[0]})
+                if tr is not None else None)
         out = np.asarray(logits)
+        if digests is not None:
+            digests = np.asarray(digests)
+        if slot is not None:
+            otrace.Tracer.end(slot)
         payload = np.ascontiguousarray(out).view(np.uint8).reshape(
             out.shape[0], out.shape[1] * 4)
         result = {
@@ -228,7 +264,7 @@ class PerceptionStep:
             "topic_idx": np.zeros(out.shape[0], dtype=np.uint32),
         }
         if digests is not None:
-            result["input_record_digests"] = np.asarray(digests)
+            result["input_record_digests"] = digests
         return result
 
     # -- batched user-logic contract -----------------------------------------
@@ -237,7 +273,13 @@ class PerceptionStep:
         from repro.data.pipeline import assemble_message_batch
         batch = assemble_message_batch(msgs)
         logits, _ = self.step_arrays(batch)
+        tr = otrace.TRACER
+        slot = (tr.begin("perception.readback", "logic",
+                         attrs={"rows": len(msgs)})
+                if tr is not None else None)
         out = np.asarray(logits)
+        if slot is not None:
+            otrace.Tracer.end(slot)
         return [(self.out_topic, m.timestamp, out[i].tobytes())
                 for i, m in enumerate(msgs)]
 
@@ -247,17 +289,29 @@ _STEPS: dict[str, PerceptionStep] = {}
 SCHEME = "perception://"
 
 
+def _model_of(ref: str) -> str:
+    return ref[len(SCHEME):] if ref.startswith(SCHEME) else ref
+
+
 def get_step(ref: str) -> PerceptionStep:
     """Resolve (and cache per process) the step a ``perception://<model>``
     logic ref names.  The cache keeps the jit trace warm across the
     partitions/scenarios of a suite — every partition of every scenario
     naming the same model shares one compiled program and one param set."""
-    model = ref[len(SCHEME):] if ref.startswith(SCHEME) else ref
+    model = _model_of(ref)
     step = _STEPS.get(model)
     if step is None:
         step = _STEPS[model] = PerceptionStep(model=model)
     return step
 
 
+def step_hlo(ref: str, rows: int, row_bytes: int) -> str:
+    """:meth:`PerceptionStep.hlo_text` of the step :func:`get_step`
+    resolves ``ref`` to, built with the same settings but no weights."""
+    return PerceptionStep(model=_model_of(ref), seed=None).hlo_text(
+        rows, row_bytes)
+
+
 __all__ = ["OUT_TOPIC", "PerceptionStep", "SCHEME", "build_step",
-           "features_to_logits", "get_step", "init_params", "resolve_config"]
+           "features_to_logits", "get_step", "init_params", "resolve_config",
+           "step_hlo"]

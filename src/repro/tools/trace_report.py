@@ -4,7 +4,7 @@
 whole run — driver and worker spans stitched into one timeline.
 Perfetto answers "what happened at t=1.38s"; this tool answers the
 coarser engineering question: **where does each scenario's time go**,
-stage by stage (read vs decode vs logic vs record vs transport vs cache
+stage by stage (read vs logic vs record vs transport vs cache
 vs aggregate), and which stage dominates:
 
     PYTHONPATH=src python -m repro.tools.trace_report trace.json

@@ -267,12 +267,14 @@ def test_stage_breakdown_attribution_and_dedup():
              attrs={"lane": "record-1"}),
         # suite-level span with no sched.task ancestor
         _rec(7, 1, "cache.load", "cache", 1, 5 * ms + 1),
-        # jitted decode+forward bills its own stage
+        # the jitted decode+forward's host side bills logic
         _rec(8, 2, "perception.step", "logic", 50 * ms, 70 * ms),
+        # the bag-cache fill bills read
+        _rec(10, 2, "bag.cache_fill", "play", 80 * ms, 85 * ms),
     ]
     bd = oexport.stage_breakdown(records)
-    assert bd["s1"] == {"read": 10 * ms, "logic": 40 * ms,
-                        "record": 20 * ms, "decode": 20 * ms}
+    assert bd["s1"] == {"read": 15 * ms, "logic": 60 * ms,
+                        "record": 20 * ms}
     assert bd["_suite"] == {"cache": 5 * ms}
 
 
