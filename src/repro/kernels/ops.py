@@ -23,11 +23,14 @@ def _interpret_default() -> bool:
     return resolve_interpret(None)
 
 
-def attention(q, k, v, *, causal=True, window=0, blk_q=128, blk_k=128,
+def attention(q, k, v, *, causal=True, window=0, blk_q=None, blk_k=None,
               interpret=None):
-    """Flash attention; layout (B, H, S, hd) / (B, KV, S, hd)."""
+    """Flash attention; layout (B, H, S, hd) / (B, KV, S, hd).  Blocks
+    default to the kernel's
+    :func:`~repro.kernels.flash_attention.block_sizes`."""
     return flash_attention(q, k, v, causal=causal, window=window,
-                           blk_q=blk_q, blk_k=blk_k, interpret=interpret)
+                           blk_q=blk_q, blk_k=blk_k,
+                           interpret=interpret).swapaxes(1, 2)
 
 
 def mamba_scan(x, dt, B, C, A, *, blk_d=128, blk_s=128, interpret=None):

@@ -1,25 +1,40 @@
 """Attention variants: GQA (+ qk-norm / QKV-bias / sliding-window / M-RoPE)
 and MLA (multi-head latent attention, compressed KV cache + absorbed decode).
 
-All sequence-level attention uses a memory-bounded chunked online-softmax
-("flash-style") implementation in pure jnp — the TPU Pallas kernel in
-``repro.kernels.flash_attention`` is numerically validated against the same
-math and is swapped in on real hardware via ``use_pallas``.
+Sequence-level attention is a memory-bounded chunked online-softmax
+("flash-style") implementation in pure jnp, :func:`chunked_attention`.
+:func:`gqa_forward` runs the fused Pallas kernel of
+``repro.kernels.flash_attention`` in its place where that kernel is
+compiled for a TPU (``kernels.compat.resolve_interpret(None)`` is False),
+no mesh over more than one device is active (a ``pallas_call`` is not
+partitioned) and each kv head's query heads fill whole lane tiles
+(``flash_attention.lane_tiled``); its gradient is the VJP of
+:func:`chunked_attention`.  Decode, MLA and cross-attention always take
+the jnp path.  The core of both paths runs under the ``attention_core``
+scope, and the ``attention`` metrics scope counts each trace of either
+path (counters ``fused``, ``chunked``).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.distributed import context as ctx
+from repro.kernels.compat import resolve_interpret
+from repro.kernels.flash_attention import flash_attention, lane_tiled
+from repro.obs import metrics as obs_metrics
 
 from .config import ModelConfig
 from .layers import ParamDef, apply_mrope, apply_rope, rms_norm
 
 NEG_INF = -1e30
+
+#: which attention path each trace of :func:`gqa_forward` took
+_PATHS = obs_metrics.scope("attention")
 
 
 class KVCache(NamedTuple):
@@ -156,6 +171,41 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.astype(q.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
+                    window: int, chunk: int) -> jax.Array:
+    """The Pallas kernel on :func:`chunked_attention`'s layout, under the
+    ``attention_core`` scope (the head-major transposes are outside it:
+    XLA folds them into the q/k norm and RoPE fusion); its gradient is
+    that of :func:`chunked_attention` with ``chunk``, recomputed from q,
+    k and v."""
+    qh, kh, vh = (x.swapaxes(1, 2) for x in (q, k, v))
+    with jax.named_scope("attention_core"):
+        return flash_attention(qh, kh, vh, causal=causal, window=window)
+
+
+def _fused_fwd(q, k, v, causal, window, chunk):
+    return fused_attention(q, k, v, causal, window, chunk), (q, k, v)
+
+
+def _fused_bwd(causal, window, chunk, res, g):
+    _, vjp = jax.vjp(functools.partial(chunked_attention, causal=causal,
+                                       window=window, chunk=chunk), *res)
+    return vjp(g)
+
+
+fused_attention.defvjp(_fused_fwd, _fused_bwd)
+
+
+def _takes_kernel(cfg: ModelConfig) -> bool:
+    """Whether :func:`gqa_forward` runs the Pallas kernel: compiled for a
+    TPU, on one device, with output columns the kernel can tile."""
+    mesh = ctx.mesh_ctx()
+    return (not resolve_interpret(None)
+            and lane_tiled(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim)
+            and (mesh is None or mesh.devices.size == 1))
+
+
 def gqa_forward(cfg: ModelConfig, p: dict, x: jax.Array,
                 positions: jax.Array, causal: bool = True,
                 ) -> tuple[jax.Array, KVCache]:
@@ -166,9 +216,15 @@ def gqa_forward(cfg: ModelConfig, p: dict, x: jax.Array,
         # the model axis instead of the (B,S,H*hd) activations
         q = ctx.constrain(q, ctx.dp(), "model", None, None)
     chunk = cfg.attn_chunk if cfg.attn_chunk > 0 else k.shape[1]
-    out = chunked_attention(q, k, v, causal=causal,
-                            window=cfg.sliding_window, chunk=chunk,
-                            unroll=cfg.unroll_inner)
+    if _takes_kernel(cfg):
+        _PATHS.counter("fused").inc()
+        out = fused_attention(q, k, v, causal, cfg.sliding_window, chunk)
+    else:
+        _PATHS.counter("chunked").inc()
+        with jax.named_scope("attention_core"):
+            out = chunked_attention(q, k, v, causal=causal,
+                                    window=cfg.sliding_window, chunk=chunk,
+                                    unroll=cfg.unroll_inner)
     if cfg.seq_sharded_attention:
         out = ctx.constrain(out, ctx.dp(), "model", None, None)
     B, S, H, hd = q.shape

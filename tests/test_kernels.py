@@ -67,6 +67,66 @@ def test_flash_attention_block_shape_invariance(blk_q, blk_k):
                                rtol=2e-5, atol=2e-5)
 
 
+# inputs in the model's layout, (B, S, H, hd), at head width 128: S 271
+# and 64 run the whole sequence as one block, S 1030 sweeps kv in
+# 512-blocks
+MASKS = {"causal": (True, 0), "noncausal": (False, 0), "window": (True, 200)}
+
+
+def _bshd(S, G, dtype, seed=0, B=2, KV=2, hd=128):
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(kq, (B, S, KV * G, hd)).astype(dtype)
+    k = jax.random.normal(kk, (B, S, KV, hd)).astype(dtype)
+    v = jax.random.normal(kv, (B, S, KV, hd)).astype(dtype)
+    return q, k, v
+
+
+@pytest.mark.parametrize("mask", MASKS)
+@pytest.mark.parametrize("G", [1, 4])
+@pytest.mark.parametrize("S", [271, 64, 1030])
+def test_fused_gqa_attention_matches_chunked_and_ref(S, G, mask):
+    from repro.kernels.flash_attention import block_sizes, flash_attention
+    from repro.models.attention import chunked_attention
+    causal, window = MASKS[mask]
+    q, k, v = _bshd(S, G, jnp.bfloat16, seed=S + G)
+    whole = block_sizes(S, S, G, 128, 2) == (S, S)
+    assert whole == (S < 1024)
+    got = flash_attention(q.swapaxes(1, 2), k.swapaxes(1, 2),
+                          v.swapaxes(1, 2), causal=causal, window=window)
+    assert got.shape == q.shape and got.dtype == jnp.bfloat16
+    got = np.asarray(got.astype(jnp.float32))
+    chunked = chunked_attention(q, k, v, causal=causal, window=window)
+    want = ref.attention_reference(
+        q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
+        causal=causal, window=window).swapaxes(1, 2)
+    for other in (chunked, want):
+        np.testing.assert_allclose(
+            got, np.asarray(other.astype(jnp.float32)), rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("mask", MASKS)
+def test_fused_gqa_attention_grad_is_chunked_grad(mask):
+    from repro.models.attention import chunked_attention, fused_attention
+    causal, window = MASKS[mask]
+    q, k, v = _bshd(96, 4, jnp.float32, seed=7)
+    w = jax.random.normal(jax.random.PRNGKey(8), q.shape)
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v) * w)
+
+    fused = loss(lambda q, k, v: fused_attention(q, k, v, causal, window,
+                                                 32))
+    plain = loss(lambda q, k, v: chunked_attention(
+        q, k, v, causal=causal, window=window, chunk=32))
+    got = jax.grad(fused, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(plain, argnums=(0, 1, 2))(q, k, v)
+    for g, w_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w_),
+                                   rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(float(fused(q, k, v)), float(plain(q, k, v)),
+                               rtol=1e-4)
+
+
 # --------------------------------------------------------------------------
 # selective scan
 # --------------------------------------------------------------------------

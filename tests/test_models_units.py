@@ -65,6 +65,30 @@ def test_chunked_attention_noncausal():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("compiled,head_dim,path", [
+    (False, 128, "chunked"),        # CPU: kernels interpret
+    (True, 128, "fused"),           # as on a TPU
+    (True, 16, "chunked"),          # a kv head's queries under a lane tile
+])
+def test_gqa_forward_dispatch(monkeypatch, compiled, head_dim, path):
+    cfg = tiny_config("qwen3-4b").replace(num_kv_heads=2, head_dim=head_dim)
+    p = init_table(jax.random.PRNGKey(0), A.gqa_table(cfg))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, cfg.d_model))
+    pos = jnp.broadcast_to(jnp.arange(24), (2, 24))
+    want, _ = A.gqa_forward(cfg, p, x, pos)
+    counters = {n: A._PATHS.counter(n) for n in ("fused", "chunked")}
+    before = {n: c.value for n, c in counters.items()}
+    # only the dispatch sees a compiled backend: the kernel itself still
+    # resolves to interpret mode here
+    monkeypatch.setattr(A, "resolve_interpret", lambda _: not compiled)
+    got, kv = A.gqa_forward(cfg, p, x, pos)
+    assert {n: c.value - before[n] for n, c in counters.items()} == {
+        n: int(n == path) for n in counters}
+    assert kv.k.shape == (2, 24, cfg.num_kv_heads, head_dim)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
 def test_moe_dispatch_matches_dense_oracle():
     """With capacity >> tokens nothing drops, so scatter dispatch must equal
     the dense run-every-expert oracle exactly."""

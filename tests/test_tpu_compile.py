@@ -1,7 +1,7 @@
 """Compile the main path's device programs for a described TPU v5e chip.
 
 Nothing runs: the installed TPU compiler lowers and compiles the Pallas
-decode kernels and the full-width perception step for a chip that is
+decode and attention kernels and the full-width perception step for a chip that is
 described, not attached, so what Mosaic or XLA would refuse on the chip
 (casts, reductions, tiling, memory) fails here first.  The topology is
 described inside a fixture, never at import time: only one process may
@@ -91,10 +91,32 @@ def test_full_width_param_init_fits_one_v5e(one_chip):
     assert mem.output_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
 
 
-def test_full_width_perception_step_fits_one_v5e(one_chip):
+#: the fused attention kernel's shapes: the qwen3-4b cell's (16 rows of
+#: 271 tokens, 32 query heads on 8 kv heads of 128), whole-sequence
+#: blocks; and longer sequences that sweep kv, with and without a window
+ATTN_SHAPES = [(16, 271, 32, 8, True, 0), (16, 271, 32, 8, False, 0),
+               (2, 1030, 32, 8, True, 0), (1, 4096, 40, 8, True, 1024)]
+
+
+@pytest.mark.parametrize("B,S,H,KV,causal,window", ATTN_SHAPES)
+def test_fused_attention_compiles_for_v5e(one_chip, B, S, H, KV, causal,
+                                          window):
+    from repro.kernels.flash_attention import flash_attention
+    q = _spec(one_chip, (B, H, S, 128), jnp.bfloat16)
+    kv = _spec(one_chip, (B, KV, S, 128), jnp.bfloat16)
+    lowered = jax.jit(functools.partial(
+        flash_attention, causal=causal, window=window,
+        interpret=False)).lower(q, kv, kv)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_full_width_perception_step_fits_one_v5e(one_chip, monkeypatch):
+    from repro.kernels.compat import INTERPRET_ENV
     from repro.models import get_model
     from repro.perception import build_step, resolve_config
 
+    # kernels compiled, as on the chip: attention takes the fused kernel
+    monkeypatch.setenv(INTERPRET_ENV, "0")
     cfg = resolve_config("qwen3-4b")
     shapes = jax.eval_shape(get_model(cfg).init_params,
                             jax.random.PRNGKey(0))
@@ -104,7 +126,8 @@ def test_full_width_perception_step_fits_one_v5e(one_chip):
                       interpret=False)
     compiled = step.lower(params, *_batch_specs(
         one_chip, STEP_ROWS, RECORD_BYTES, False)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%_flash_attention" in text
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, used
