@@ -3,8 +3,8 @@ registers all of them; each module holds exactly one architecture with the
 exact published shape, plus ``tiny()`` reductions for smoke tests."""
 
 from . import (falcon_mamba_7b, granite_moe_1b_a400m, grok_1_314b,
-               hymba_1_5b, minicpm3_4b, qwen2_5_32b, qwen2_vl_7b, qwen3_4b,
-               seamless_m4t_large_v2, yi_34b)
+               hymba_1_5b, jamba2_3b, minicpm3_4b, qwen2_5_32b, qwen2_vl_7b,
+               qwen3_4b, seamless_m4t_large_v2, yi_34b)
 from .tiny import tiny_config
 
 ALL_ARCHS = [

@@ -37,4 +37,7 @@ def tiny_config(name: str) -> ModelConfig:
         kw.update(num_encoder_layers=2)
     if cfg.rope_type == "mrope":
         kw.update(mrope_sections=(2, 3, 3))
+    if cfg.attn_layer_period:
+        # one whole period with both kinds of layer: ssm, attention, ssm, ssm
+        kw.update(num_layers=4, attn_layer_period=4, attn_layer_offset=1)
     return cfg.replace(**kw)

@@ -33,10 +33,12 @@ def attention(q, k, v, *, causal=True, window=0, blk_q=None, blk_k=None,
                            interpret=interpret).swapaxes(1, 2)
 
 
-def mamba_scan(x, dt, B, C, A, *, blk_d=128, blk_s=128, interpret=None):
-    """Selective scan; x/dt (b,S,di), B/C (b,S,N), A (di,N) negative."""
+def mamba_scan(x, dt, B, C, A, *, blk_d=None, blk_s=None, interpret=None):
+    """Selective scan's y; x/dt (b,S,di), B/C (b,S,N), A (di,N) negative.
+    Blocks default to the kernel's
+    :func:`~repro.kernels.selective_scan.block_sizes`."""
     return selective_scan(x, dt, B, C, A, blk_d=blk_d, blk_s=blk_s,
-                          interpret=interpret)
+                          interpret=interpret)[0]
 
 
 def decode_records(payload, scale, zero_point, lengths, *, blk_r=8,

@@ -91,7 +91,7 @@ def _project_qkv(cfg: ModelConfig, p: dict, x: jax.Array, positions):
     if cfg.rope_type == "mrope":
         q = apply_mrope(q, positions, cfg.rope_theta, cfg.mrope_sections)
         k = apply_mrope(k, positions, cfg.rope_theta, cfg.mrope_sections)
-    else:
+    elif cfg.rope_type == "rope":
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v

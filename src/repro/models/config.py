@@ -1,5 +1,10 @@
 """Model configuration: one dataclass covering all assigned families
-(dense GQA / MLA / MoE / SSM / hybrid / VLM backbone / enc-dec audio)."""
+(dense GQA / MLA / MoE / SSM / hybrid / VLM backbone / enc-dec audio).
+
+A ``hybrid`` config runs attention and the SSM side by side in every layer
+(hymba), unless ``attn_layer_period`` is set: then each layer has one mixer,
+attention where ``i % attn_layer_period == attn_layer_offset`` and the SSM
+elsewhere (Jamba's interleaved stack)."""
 
 from __future__ import annotations
 
@@ -26,7 +31,7 @@ class ModelConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
-    rope_type: str = "rope"          # rope | mrope
+    rope_type: str = "rope"          # rope | mrope | none
     mrope_sections: tuple = ()       # e.g. (16, 24, 24) halves of head_dim
     sliding_window: int = 0          # 0 = full causal attention
 
@@ -47,6 +52,16 @@ class ModelConfig:
     ssm_d_inner: int = 0
     ssm_conv: int = 4
     ssm_dt_rank: int = 0
+    ssm_inner_norms: bool = False    # RMSNorm on dt, B, C (Jamba's mixer)
+    # initial A_log and dt bias: "constant" (ones, zeros) or "mamba" (the
+    # Mamba paper's: A = -(1..N), dt log-uniform on [1e-3, 1e-1])
+    ssm_init: str = "constant"
+
+    # interleaved layers (hybrid family): attention where
+    # i % attn_layer_period == attn_layer_offset, the SSM elsewhere;
+    # 0 = every layer alike
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
 
     # encoder-decoder (audio family)
     is_encoder_decoder: bool = False
@@ -84,6 +99,16 @@ class ModelConfig:
     ssm_bf16: bool = False
 
     def __post_init__(self):
+        if self.rope_type not in ("rope", "mrope", "none"):
+            raise ValueError(f"rope_type {self.rope_type!r}: rope | mrope | "
+                             f"none")
+        if self.ssm_init not in ("constant", "mamba"):
+            raise ValueError(f"ssm_init {self.ssm_init!r}: constant | mamba")
+        if self.attn_layer_period and not (
+                self.family == "hybrid"
+                and 0 <= self.attn_layer_offset < self.attn_layer_period):
+            raise ValueError("attn_layer_period needs the hybrid family and "
+                             "0 <= attn_layer_offset < attn_layer_period")
         if self.attention == "gqa" and self.num_heads and not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.num_heads)
@@ -118,6 +143,24 @@ class ModelConfig:
     @property
     def has_ssm(self) -> bool:
         return self.family in ("ssm", "hybrid")
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """Each layer's mixer, in order, for an interleaved stack:
+        ``"attention"`` or ``"ssm"``; empty where every layer is alike."""
+        if not self.attn_layer_period:
+            return ()
+        return tuple("attention" if i % self.attn_layer_period
+                     == self.attn_layer_offset else "ssm"
+                     for i in range(self.num_layers))
+
+    def kind_config(self, kind: str) -> "ModelConfig":
+        """The config one kind of layer of an interleaved stack is built
+        and run with: attention only, or the SSM only."""
+        flat = dict(attn_layer_period=0, attn_layer_offset=0)
+        if kind == "attention":
+            return self.replace(family="dense", **flat)
+        return self.replace(family="ssm", attention="none", **flat)
 
     @property
     def sub_quadratic(self) -> bool:
@@ -174,16 +217,24 @@ class ModelConfig:
             if not self.has_ssm:
                 return 0
             di, st, dr = self.ssm_d_inner, self.ssm_state, self.ssm_dt_rank
-            return (D * 2 * di + di * self.ssm_conv
+            inner_norms = dr + 2 * st if self.ssm_inner_norms else 0
+            return (D * 2 * di + di * self.ssm_conv + di
                     + di * (dr + 2 * st) + dr * di + di
-                    + di * st + di + di * D)
+                    + di * st + di + di * D + inner_norms)
 
         a, (mt, ma), s = attn_params(), mlp_params(), ssm_params()
         norms = 2 * D
-        layer_total = a + mt + s + norms
-        layer_active = a + ma + s + norms
-        total = L * layer_total + emb + D
-        active = L * layer_active + emb + D
+        if self.layer_kinds:
+            # one mixer a layer: count each kind's layers with their own
+            n_attn = self.layer_kinds.count("attention")
+            mix = n_attn * a + (L - n_attn) * s
+            total = mix + L * (mt + norms) + emb + D
+            active = mix + L * (ma + norms) + emb + D
+        else:
+            layer_total = a + mt + s + norms
+            layer_active = a + ma + s + norms
+            total = L * layer_total + emb + D
+            active = L * layer_active + emb + D
         if self.is_encoder_decoder:
             # encoder layers: self-attn + mlp; decoder adds cross-attn
             enc = self.num_encoder_layers * (a + mt + norms)

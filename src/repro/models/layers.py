@@ -21,7 +21,8 @@ import numpy as np
 class ParamDef:
     shape: tuple
     axes: tuple                       # logical axis names, len == len(shape)
-    init: str = "normal"              # normal | zeros | ones | small_normal
+    init: str = "normal"              # normal | zeros | ones | s4d_real
+                                      # | dt_bias
     scale: Optional[float] = None     # stddev override
 
 
@@ -34,6 +35,13 @@ def init_table(key: jax.Array, table: dict[str, ParamDef],
             out[name] = jnp.zeros(pd.shape, dtype)
         elif pd.init == "ones":
             out[name] = jnp.ones(pd.shape, dtype)
+        elif pd.init == "s4d_real":
+            # A_log = log(1..N) along the last axis: A = -(1..N)
+            out[name] = jnp.broadcast_to(jnp.log(jnp.arange(
+                1, pd.shape[-1] + 1, dtype=jnp.float32)), pd.shape).astype(
+                    dtype)
+        elif pd.init == "dt_bias":
+            out[name] = dt_bias(k, pd.shape).astype(dtype)
         else:
             fan_in = pd.shape[0] if len(pd.shape) >= 2 else pd.shape[-1]
             if len(pd.shape) == 3:    # stacked expert weights: (E, in, out)
@@ -42,6 +50,21 @@ def init_table(key: jax.Array, table: dict[str, ParamDef],
             out[name] = (jax.random.normal(k, pd.shape, jnp.float32)
                          * std).astype(dtype)
     return out
+
+
+#: Mamba's initial step sizes: log-uniform on [DT_MIN, DT_MAX], floored
+DT_MIN, DT_MAX, DT_FLOOR = 1e-3, 1e-1, 1e-4
+
+
+def dt_bias(key: jax.Array, shape: tuple) -> jax.Array:
+    """Mamba's (Gu & Dao, arXiv:2312.00752) initial dt bias, float32: the
+    inverse softplus of a step size drawn log-uniform on [DT_MIN, DT_MAX]
+    and floored at DT_FLOOR."""
+    lo, hi = math.log(DT_MIN), math.log(DT_MAX)
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32) * (hi - lo)
+                 + lo)
+    dt = jnp.maximum(dt, DT_FLOOR)
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def table_specs(table: dict[str, ParamDef]) -> dict[str, tuple]:

@@ -5,11 +5,17 @@ leading L axis), ``forward``/``prefill``/``decode_step`` are jit-able, and
 ``param_specs`` returns the logical-axis pytree the sharding layer consumes.
 Layers run under ``jax.lax.scan`` (bounded HLO at 512 devices) with optional
 per-block remat.
+
+An interleaved stack (``ModelConfig.layer_kinds``: Jamba) keeps one stack
+per kind of layer, ``params["layers"][kind]`` in the layers' order, and runs
+each run of consecutive layers of one kind as one scan over its stack, so
+the published order is kept; its prefill and decode are not implemented.
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -198,7 +204,14 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         k_emb, embed_table(cfg.padded_vocab, cfg.d_model,
                            cfg.tie_embeddings), dtype)}
     layer_keys = jax.random.split(k_layers, cfg.num_layers)
-    if cfg.scan_layers:
+    if cfg.layer_kinds:
+        # layer i draws from layer_keys[i] by its kind's tables; each kind's
+        # layers are stacked in order
+        params["layers"] = {
+            kind: jax.vmap(lambda k, c=cfg.kind_config(kind): init_block(
+                c, k, dtype))(layer_keys[jnp.array(idx)])
+            for kind, idx in _kind_layers(cfg).items()}
+    elif cfg.scan_layers:
         params["layers"] = jax.vmap(
             lambda k: init_block(cfg, k, dtype))(layer_keys)
     else:
@@ -206,13 +219,37 @@ def init_params(cfg: ModelConfig, key: jax.Array,
     return params
 
 
+def _kind_layers(cfg: ModelConfig) -> dict[str, list[int]]:
+    """{kind: indices of its layers, in order} of an interleaved stack."""
+    out: dict[str, list[int]] = {}
+    for i, kind in enumerate(cfg.layer_kinds):
+        out.setdefault(kind, []).append(i)
+    return out
+
+
+def _kind_runs(cfg: ModelConfig) -> list[tuple[str, int, int]]:
+    """(kind, start, stop) of each run of consecutive layers of one kind,
+    in order; start and stop index the kind's stack."""
+    runs, seen = [], {}
+    for kind, run in itertools.groupby(cfg.layer_kinds):
+        start = seen.get(kind, 0)
+        seen[kind] = start + len(list(run))
+        runs.append((kind, start, seen[kind]))
+    return runs
+
+
 def param_specs(cfg: ModelConfig) -> dict:
-    blocks = block_specs(cfg, cfg.scan_layers)
+    if cfg.layer_kinds:
+        layers = {kind: block_specs(cfg.kind_config(kind), True)
+                  for kind in _kind_layers(cfg)}
+    else:
+        blocks = block_specs(cfg, cfg.scan_layers)
+        layers = (blocks if cfg.scan_layers
+                  else [blocks for _ in range(cfg.num_layers)])
     return {
         "embed": table_specs(
             embed_table(cfg.padded_vocab, cfg.d_model, cfg.tie_embeddings)),
-        "layers": (blocks if cfg.scan_layers
-                   else [blocks for _ in range(cfg.num_layers)]),
+        "layers": layers,
     }
 
 
@@ -243,14 +280,40 @@ def _embed_inputs(cfg: ModelConfig, params, batch: dict) -> tuple:
     return x, positions
 
 
+def _remat(cfg: ModelConfig, block):
+    if cfg.remat == "none":
+        return block
+    return jax.checkpoint(
+        block, policy=jax.checkpoint_policies.nothing_saveable
+        if cfg.remat == "full" else
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+
+
+def _run_interleaved(cfg: ModelConfig, params, x, positions):
+    """Each run of consecutive layers of one kind as one scan over the
+    indices of its kind's stack."""
+    for kind, start, stop in _kind_runs(cfg):
+        block = _remat(cfg, functools.partial(block_forward,
+                                              cfg.kind_config(kind)))
+        stack = params["layers"][kind]
+
+        def body(h, i, block=block, stack=stack):
+            lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, i, keepdims=False), stack)
+            return block(lp, h, positions)[0], None
+
+        x, _ = jax.lax.scan(body, x, jnp.arange(start, stop))
+    return x
+
+
 def _run_layers(cfg: ModelConfig, params, x, positions,
                 collect_caches: bool = False):
-    block = functools.partial(block_forward, cfg)
-    if cfg.remat != "none":
-        block = jax.checkpoint(
-            block, policy=jax.checkpoint_policies.nothing_saveable
-            if cfg.remat == "full" else
-            jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+    if cfg.layer_kinds:
+        if collect_caches:
+            raise NotImplementedError(
+                f"{cfg.name}: prefill of an interleaved stack")
+        return _run_interleaved(cfg, params, x, positions), None
+    block = _remat(cfg, functools.partial(block_forward, cfg))
     if cfg.scan_layers:
         def body(h, lp):
             h2, caches = block(lp, h, positions)
@@ -370,6 +433,9 @@ def init_decode_state(cfg: ModelConfig, batch: int, s_max: int,
                       index: int = 0) -> DecodeState:
     """Empty caches at full length — the decode-only benchmark entrypoint
     (the decode_32k / long_500k cells lower THIS, with index = seq_len)."""
+    if cfg.layer_kinds:
+        raise NotImplementedError(
+            f"{cfg.name}: decode of an interleaved stack")
     dtype = jnp.dtype(cfg.dtype)
     L = cfg.num_layers
 
@@ -396,6 +462,9 @@ def init_decode_state(cfg: ModelConfig, batch: int, s_max: int,
 def decode_step(cfg: ModelConfig, params: dict, state: DecodeState,
                 tokens: jax.Array) -> DecodeState:
     """One token for every sequence. tokens: (B, 1) int32."""
+    if cfg.layer_kinds:
+        raise NotImplementedError(
+            f"{cfg.name}: decode of an interleaved stack")
     dtype = jnp.dtype(cfg.dtype)
     x = embed_tokens(params["embed"], tokens, dtype)
     index = state.index

@@ -190,6 +190,61 @@ def test_selective_scan_matches_model_ssm():
                                rtol=2e-4, atol=2e-4)
 
 
+def _scan_inputs(b, S, di, N, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(ks[0], (b, S, di)),
+            jax.nn.softplus(jax.random.normal(ks[1], (b, S, di)) - 1.0),
+            jax.random.normal(ks[2], (b, S, N)),
+            jax.random.normal(ks[3], (b, S, N)),
+            -jnp.exp(jax.random.normal(ks[4], (di, N)) * 0.5))
+
+
+@pytest.mark.parametrize("b,S,di,N,blk_s", [
+    (2, 271, 256, 16, None),    # the cell's odd S: one whole-sequence block
+    (1, 271, 384, 16, 128),     # S cut into 128-step blocks, last one short
+    (2, 37, 128, 8, None),
+])
+def test_selective_scan_kernel_matches_jnp_scan_and_ref(b, S, di, N, blk_s):
+    """The kernel's y and last state against the model's jnp chunked scan
+    and the sequential oracle.  All three run in float32; they differ only
+    in the order of the decays' products (the associative scan pairs them
+    in a tree), so 2e-4 on outputs of order 1-10 is rounding."""
+    from repro.kernels.selective_scan import selective_scan
+    from repro.models.ssm import _chunked_scan
+    x, dt, B, C, A = _scan_inputs(b, S, di, N)
+    y, h = selective_scan(x, dt, B, C, A, blk_s=blk_s)
+    y_jnp, h_jnp = _chunked_scan(x, dt, B, C, A, block=64)
+    want = ref.selective_scan_reference(x, dt, B, C, A)
+    assert y.shape == (b, S, di) and h.shape == (b, di, N)
+    for got, w in ((y, want), (y, y_jnp), (h, h_jnp)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_selective_scan_kernel_grad_is_jnp_grad():
+    """``kernel_scan``'s VJP is the jnp scan's, recomputed from the inputs;
+    with a loss linear in y and the state the cotangents are the same, so
+    the gradients are the same arithmetic."""
+    from repro.models.ssm import _chunked_scan, kernel_scan
+    args = _scan_inputs(2, 40, 128, 8, seed=3)
+    wy = jax.random.normal(jax.random.PRNGKey(4), (2, 40, 128))
+    wh = jax.random.normal(jax.random.PRNGKey(5), (2, 128, 8))
+
+    def loss(scan):
+        def f(*a):
+            y, h = scan(*a)
+            return jnp.sum(y * wy) + jnp.sum(h * wh)
+        return f
+
+    got = jax.grad(loss(lambda *a: kernel_scan(*a, 16)),
+                   argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(loss(lambda *a: _chunked_scan(*a, block=16)),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-6, atol=1e-6)
+
+
 # --------------------------------------------------------------------------
 # sensor decode
 # --------------------------------------------------------------------------

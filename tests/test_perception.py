@@ -258,6 +258,35 @@ def test_perception_scheme_runs_as_batched_logic(tmp_path):
     assert a.report.output_image == b.report.output_image
 
 
+def test_perception_scheme_runs_interleaved_jamba(tmp_path):
+    """``perception://jamba2-3b-tiny``: a whole period of the interleaved
+    stack (ssm, attention, ssm, ssm) as batched logic; the verdict
+    manifest counts which scan each trace of the SSM took (the jnp scan on
+    the CPU)."""
+    import json
+
+    from repro.models import ssm as SSM
+    from repro.perception import _STEPS, get_step
+
+    # the counters are the process's: read what this suite adds
+    before = {n: SSM._PATHS.counter(n).value for n in ("jnp", "kernel")}
+    ref = "perception://jamba2-3b-tiny"
+    _STEPS.pop("jamba2-3b-tiny", None)
+    step = get_step(ref)
+    assert step.cfg.layer_kinds == ("ssm", "attention", "ssm", "ssm")
+    bag_path = _perception_bag(tmp_path, n=32, payload=256)
+    log = str(tmp_path / "verdicts.jsonl")
+    sc = Scenario("jamba", bag_path, ref, batch_size=16, num_partitions=1)
+    v = ScenarioSuite([sc], num_workers=1).run(
+        timeout=300, verdict_log=log)["jamba"]
+    assert v.passed and not v.vacuous
+    assert v.report.messages_out == 32
+    counts = json.load(open(log + ".manifest.json"))["metrics"]["ssm"]
+    # one trace a run of Mamba layers: ssm, then ssm, ssm
+    assert counts["jnp"] - before["jnp"] == 2
+    assert counts.get("kernel", 0) == before["kernel"]
+
+
 def test_perception_scheme_requires_batch_size_and_thread_backend(tmp_path):
     bag_path = _perception_bag(tmp_path, n=8)
     with pytest.raises(ValueError, match="batch_size"):

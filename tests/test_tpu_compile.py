@@ -9,6 +9,7 @@ load the TPU library, and every test worker imports this module.
 """
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -93,9 +94,11 @@ def test_full_width_param_init_fits_one_v5e(one_chip):
 
 #: the fused attention kernel's shapes: the qwen3-4b cell's (16 rows of
 #: 271 tokens, 32 query heads on 8 kv heads of 128), whole-sequence
-#: blocks; and longer sequences that sweep kv, with and without a window
+#: blocks; longer sequences that sweep kv, with and without a window; and
+#: the jamba2-3b cell's MQA (20 query heads on 1 kv head)
 ATTN_SHAPES = [(16, 271, 32, 8, True, 0), (16, 271, 32, 8, False, 0),
-               (2, 1030, 32, 8, True, 0), (1, 4096, 40, 8, True, 1024)]
+               (2, 1030, 32, 8, True, 0), (1, 4096, 40, 8, True, 1024),
+               (16, 271, 20, 1, True, 0)]
 
 
 @pytest.mark.parametrize("B,S,H,KV,causal,window", ATTN_SHAPES)
@@ -134,3 +137,48 @@ def test_full_width_perception_step_fits_one_v5e(one_chip, monkeypatch):
     # the bf16 params are most of it: 4.4 B of them at 2 bytes each
     n_params = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
     assert 4.0e9 < n_params < 4.5e9
+
+
+#: the selective scan's shapes: the jamba2-3b cell's (16 rows of 271
+#: steps, d_inner 5120, N 16), the whole sequence one block; and a long
+#: sequence cut into blocks, the last one short
+SCAN_SHAPES = [(16, 271, 5120, 16), (2, 4100, 5120, 16)]
+
+
+@pytest.mark.parametrize("b,S,di,N", SCAN_SHAPES)
+def test_selective_scan_compiles_for_v5e(one_chip, b, S, di, N):
+    from repro.kernels.selective_scan import selective_scan
+    f32 = jnp.float32
+    lowered = jax.jit(functools.partial(selective_scan, interpret=False)).lower(
+        _spec(one_chip, (b, S, di), f32), _spec(one_chip, (b, S, di), f32),
+        _spec(one_chip, (b, S, N), f32), _spec(one_chip, (b, S, N), f32),
+        _spec(one_chip, (di, N), f32))
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def test_full_width_jamba_step_fits_one_v5e(one_chip, monkeypatch):
+    """The jamba2-3b cell's step (16 records of 694,400 B) as the chip
+    runs it: the scan kernel in every Mamba layer, the fused attention in
+    the two attention layers."""
+    from repro.kernels.compat import INTERPRET_ENV
+    from repro.models import get_model
+    from repro.perception import build_step, resolve_config
+
+    monkeypatch.setenv(INTERPRET_ENV, "0")
+    cfg = resolve_config("jamba2-3b")
+    shapes = jax.eval_shape(get_model(cfg).init_params,
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: _spec(one_chip, s.shape, s.dtype), shapes)
+    step = build_step(cfg, out_features=16, metrics=False, donate=False,
+                      interpret=False)
+    text = step.lower(params, *_batch_specs(
+        one_chip, 16, 694_400, False)).compile().as_text()
+    # the decode kernel, one scan kernel (named after its scope) in the
+    # loop body of each of the three runs of Mamba layers, and the two
+    # attention layers' kernels
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert len(re.findall(r"%ssm_scan[.\d]* = ", text)) == 3
+    assert len(re.findall(r"%_flash_attention[.\d]* = ", text)) == 2
+    n_params = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+    assert 3.0e9 < n_params < 3.1e9
