@@ -22,12 +22,15 @@ from __future__ import annotations
 import hashlib
 import heapq
 import io
+import mmap
 import os
 import struct
 import threading
 from dataclasses import dataclass, field
-from typing import Callable, Iterable, Iterator, Optional, Sequence, Union
+from typing import (Callable, Iterable, Iterator, NamedTuple, Optional,
+                    Sequence, Union)
 
+from repro.obs import metrics as obs_metrics
 from repro.shm import SegmentHandle, read_segment
 
 _MAGIC = b"REPROBAG"
@@ -35,6 +38,13 @@ _VERSION = 2
 _HDR = struct.Struct("<IQ")          # record_count, payload_len
 _REC = struct.Struct("<IQI")         # topic_id, timestamp_ns, data_len
 DEFAULT_CHUNK_BYTES = 768 * 1024     # rosbag's default chunk threshold
+
+#: how each bag-cache fill carried its chunks: ``raw_chunks`` copied as
+#: bytes, ``decoded_chunks`` cut by the selection and re-encoded record by
+#: record (both registered up front, so a suite's manifest shows the zero)
+_FILL = obs_metrics.scope("bag_cache")
+_RAW_CHUNKS = _FILL.counter("raw_chunks")
+_DECODED_CHUNKS = _FILL.counter("decoded_chunks")
 
 
 @dataclass(frozen=True)
@@ -51,6 +61,14 @@ class ChunkInfo:
     t_min: int
     t_max: int
     topics: set = field(default_factory=set)
+
+
+class SelectionImage(NamedTuple):
+    """A memory-bag image of a bag selection (:meth:`Bag.selection_image`)
+    and how its chunks got there."""
+    image: "bytes | mmap.mmap"
+    raw_chunks: int          # carried as the source's own chunk bytes
+    decoded_chunks: int      # cut by the selection: re-encoded per record
 
 
 class ChunkedFile:
@@ -95,6 +113,16 @@ class ChunkedFile:
             self._f.seek(offset)
             record_count, payload_len = _HDR.unpack(self._f.read(_HDR.size))
             return self._f.read(payload_len), record_count
+
+    def read_into(self, offset: int, buf: memoryview) -> None:
+        """Fill ``buf`` with the bytes at ``offset``: one ranged read,
+        which runs without the GIL (the bag-cache fill's whole-chunk copy)."""
+        with self._lock:
+            self._f.seek(offset)
+            got = self._f.readinto(buf)
+        if got != len(buf):
+            raise ValueError(f"short read at {offset}: {got} of "
+                             f"{len(buf)} bytes")
 
     def write_blob(self, blob: bytes) -> int:
         """Raw append (used for the index block)."""
@@ -173,11 +201,13 @@ class MemoryChunkedFile(ChunkedFile):
             return off
 
     def read_chunk(self, offset: int) -> tuple[bytes, int]:
+        """Read mode returns a view into the image, not a copy: the reader
+        copies each record's data out once, and nothing else."""
         with self._lock:
             if self._ro is not None:
                 record_count, payload_len = _HDR.unpack_from(self._ro, offset)
                 start = offset + _HDR.size
-                return bytes(self._ro[start:start + payload_len]), record_count
+                return self._ro[start:start + payload_len], record_count
             record_count, payload = self._chunks[offset]
             return payload, record_count
 
@@ -197,6 +227,10 @@ class MemoryChunkedFile(ChunkedFile):
         # write-mode read (rare: only the index loader) — materialise
         img = self.image()
         return img[offset:offset + length]
+
+    def read_into(self, offset: int, buf: memoryview) -> None:
+        with self._lock:
+            buf[:] = self._ro[offset:offset + len(buf)]
 
     def size(self) -> int:
         with self._lock:
@@ -355,17 +389,23 @@ class Bag:
         self._pending.clear()
         self._pending_records.clear()
 
-    def _write_index(self) -> None:
+    @classmethod
+    def _index_blob(cls, topic_names: Sequence[str],
+                    chunks: Sequence[ChunkInfo]) -> bytes:
         blob = bytearray()
-        names = "\x00".join(self._topic_names).encode()
+        names = "\x00".join(topic_names).encode()
         blob += struct.pack("<I", len(names)) + names
-        blob += struct.pack("<I", len(self._chunks))
-        for c in self._chunks:
-            blob += self._INDEX.pack(c.offset, c.record_count, c.t_min, c.t_max)
+        blob += struct.pack("<I", len(chunks))
+        for c in chunks:
+            blob += cls._INDEX.pack(c.offset, c.record_count, c.t_min, c.t_max)
             blob += struct.pack("<I", len(c.topics))
             for tid in sorted(c.topics):
                 blob += struct.pack("<I", tid)
-        off = self._cf.write_blob(bytes(blob))
+        return bytes(blob)
+
+    def _write_index(self) -> None:
+        blob = self._index_blob(self._topic_names, self._chunks)
+        off = self._cf.write_blob(blob)
         self._cf.write_blob(struct.pack("<QQ", off, len(blob)) + b"RIDX")
 
     def close(self) -> None:
@@ -394,6 +434,7 @@ class Bag:
         off, blen = struct.unpack("<QQ", tail[:16])
         if tail[16:] != b"RIDX" or off + blen > size:
             raise ValueError("bag missing index (not closed?)")
+        self._index_offset = off      # the chunks end where the index starts
         blob = self._cf.read_blob(off, blen)
         pos = 0
         (nlen,) = struct.unpack_from("<I", blob, pos); pos += 4
@@ -413,6 +454,13 @@ class Bag:
         return list(self._topic_names)
 
     @property
+    def indexed_topics(self) -> list[str]:
+        """Topics that some chunk holds, in table order: a
+        :meth:`selection_image` keeps its source's whole topic table."""
+        held = set().union(*(c.topics for c in self._chunks))
+        return [t for i, t in enumerate(self._topic_names) if i in held]
+
+    @property
     def num_chunks(self) -> int:
         return len(self._chunks)
 
@@ -423,15 +471,23 @@ class Bag:
     def chunk_infos(self) -> list[ChunkInfo]:
         return list(self._chunks)
 
-    def _iter_chunk(self, info: ChunkInfo) -> Iterator[Message]:
-        payload, record_count = self._cf.read_chunk(info.offset)
+    @staticmethod
+    def _kept_records(payload: bytes, record_count: int,
+                      want: Optional[set[int]], start: Optional[int],
+                      end: Optional[int],
+                      ) -> Iterator[tuple[int, int, int, int]]:
+        """``(topic_id, timestamp, first byte, end byte)`` of each record of
+        one chunk payload that the selection keeps; its data follows the
+        record's ``_REC`` header."""
         pos = 0
         for _ in range(record_count):
             tid, ts, dlen = _REC.unpack_from(payload, pos)
-            pos += _REC.size
-            data = payload[pos:pos + dlen]
-            pos += dlen
-            yield Message(self._topic_names[tid], ts, data)
+            nxt = pos + _REC.size + dlen
+            if ((want is None or tid in want)
+                    and (start is None or ts >= start)
+                    and (end is None or ts < end)):
+                yield tid, ts, pos, nxt
+            pos = nxt
 
     def content_digest(self) -> str:
         """Streaming chunk-level SHA-256 of the bag's logical content.
@@ -459,6 +515,33 @@ class Bag:
             h.update(payload)
         return h.hexdigest()
 
+    def _selected_chunks(self, want: Optional[set[int]],
+                         start: Optional[int], end: Optional[int],
+                         chunk_range: Optional[tuple[int, int]],
+                         ) -> Iterator[int]:
+        """Indices of the chunks the index says hold part of a selection;
+        ``want`` is the selection's topic ids (None: every topic)."""
+        if want is not None and not want:
+            return
+        idx = range(len(self._chunks))
+        if chunk_range is not None:
+            idx = idx[chunk_range[0]:chunk_range[1]]
+        for i in idx:
+            info = self._chunks[i]
+            if start is not None and info.t_max < start:
+                continue
+            if end is not None and info.t_min >= end:
+                continue
+            if want is not None and not (info.topics & want):
+                continue
+            yield i
+
+    def _topic_ids(self, topics: Optional[Sequence[str]]
+                   ) -> Optional[set[int]]:
+        if topics is None:
+            return None
+        return {self._topics[t] for t in topics if t in self._topics}
+
     def read_messages(self, topics: Optional[Sequence[str]] = None,
                       start: Optional[int] = None,
                       end: Optional[int] = None,
@@ -466,29 +549,113 @@ class Bag:
                       ) -> Iterator[Message]:
         """Time-ordered replay.  ``chunk_range=(lo, hi)`` restricts to a chunk
         slice — this is the partitioning handle the scheduler uses."""
-        want: Optional[set[int]] = None
-        if topics is not None:
-            want = {self._topics[t] for t in topics if t in self._topics}
-            if not want:
-                return
+        want = self._topic_ids(topics)
+        names = self._topic_names
+        for i in self._selected_chunks(want, start, end, chunk_range):
+            payload, record_count = self._cf.read_chunk(self._chunks[i].offset)
+            for tid, ts, pos, nxt in self._kept_records(
+                    payload, record_count, want, start, end):
+                # bytes() copies a memory image's view once; a disk
+                # payload's slice is already bytes, and passes as is
+                yield Message(names[tid], ts,
+                              bytes(payload[pos + _REC.size:nxt]))
+
+    def selection_image(self, topics: Optional[Sequence[str]] = None,
+                        start: Optional[int] = None,
+                        end: Optional[int] = None,
+                        chunk_range: Optional[tuple[int, int]] = None,
+                        ) -> SelectionImage:
+        """Memory-bag image of what :meth:`read_messages` selects: the
+        ROSBag cache fill (§3.2), built chunk by chunk from the index.
+
+        A chunk the selection holds wholly (every topic wanted, every
+        timestamp in ``[start, end)``) is carried as its own bytes, and each
+        contiguous run of such chunks is one ranged read of the lower tier
+        (on disk, a ``readinto`` that runs without the GIL).  Only chunks
+        the selection cuts are decoded, filtered record by record and
+        re-encoded.  Message order is chunk order, then record order, as
+        :meth:`read_messages` yields it.  The image keeps this bag's whole
+        topic table, so raw chunks keep their topic ids: use
+        :attr:`indexed_topics` for the topics the selection holds.  A
+        memory bag selected whole returns its own image.
+        """
+        if self._writable:
+            raise RuntimeError("selection_image requires a read-mode bag")
+        want = self._topic_ids(topics)
         chunks = self._chunks
-        if chunk_range is not None:
-            chunks = chunks[chunk_range[0]:chunk_range[1]]
-        for info in chunks:
-            if start is not None and info.t_max < start:
+        pos = len(_MAGIC) + 4
+        index: list[ChunkInfo] = []
+        # what goes between the header and the index: [source offset,
+        # length] of a raw run, or the bytes of a re-encoded chunk
+        parts: list = []
+        raw = decoded = 0
+        for i in self._selected_chunks(want, start, end, chunk_range):
+            info = chunks[i]
+            if ((want is None or info.topics <= want)
+                    and (start is None or info.t_min >= start)
+                    and (end is None or info.t_max < end)):
+                nxt = (chunks[i + 1].offset if i + 1 < len(chunks)
+                       else self._index_offset)
+                n = nxt - info.offset
+                if parts and isinstance(parts[-1], list) \
+                        and sum(parts[-1]) == info.offset:
+                    parts[-1][1] += n
+                else:
+                    parts.append([info.offset, n])
+                index.append(ChunkInfo(pos, info.record_count, info.t_min,
+                                       info.t_max, set(info.topics)))
+                pos += n
+                raw += 1
                 continue
-            if end is not None and info.t_min >= end:
+            decoded += 1
+            payload, record_count = self._cf.read_chunk(info.offset)
+            kept = list(self._kept_records(payload, record_count, want,
+                                           start, end))
+            if not kept:
                 continue
-            if want is not None and not (info.topics & want):
-                continue
-            for msg in self._iter_chunk(info):
-                if want is not None and self._topics.get(msg.topic) not in want:
-                    continue
-                if start is not None and msg.timestamp < start:
-                    continue
-                if end is not None and msg.timestamp >= end:
-                    continue
-                yield msg
+            view = memoryview(payload)
+            body = b"".join(view[a:b] for _, _, a, b in kept)
+            stamps = [ts for _, ts, _, _ in kept]
+            index.append(ChunkInfo(pos, len(kept), min(stamps), max(stamps),
+                                   {tid for tid, _, _, _ in kept}))
+            parts.append(_HDR.pack(len(kept), len(body)) + body)
+            pos += _HDR.size + len(body)
+        _RAW_CHUNKS.inc(raw)
+        _DECODED_CHUNKS.inc(decoded)
+        blob = self._index_blob(self._topic_names, index)
+        tail = struct.pack("<QQ", pos, len(blob)) + b"RIDX"
+        total = pos + len(blob) + len(tail)
+        if (not decoded and raw == len(chunks)
+                and isinstance(self._cf, MemoryChunkedFile)):
+            whole = self._cf.image()
+            if len(whole) == total:
+                return SelectionImage(whole, raw, 0)
+        # an anonymous mapping, not bytearray(total): its pages arrive
+        # zeroed inside the reads that fill them, with the GIL released,
+        # where a bytearray would first memset them all holding it
+        image = mmap.mmap(-1, total,
+                          flags=mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS)
+        out = memoryview(image)
+        out[:len(_MAGIC) + 4] = _MAGIC + struct.pack("<I", _VERSION)
+        at = len(_MAGIC) + 4
+        for part in parts:
+            if isinstance(part, list):
+                src, n = part
+                self._cf.read_into(src, out[at:at + n])
+            else:
+                n = len(part)
+                out[at:at + n] = part
+            at += n
+        # the raw runs' extents came from the index: every chunk header
+        # must agree with them
+        for info, nxt in zip(index, [c.offset for c in index[1:]] + [pos]):
+            record_count, payload_len = _HDR.unpack_from(image, info.offset)
+            if (record_count != info.record_count
+                    or info.offset + _HDR.size + payload_len != nxt):
+                raise ValueError("bag chunks are not where its index says")
+        out[pos:] = blob + tail
+        out.release()
+        return SelectionImage(image, raw, decoded)
 
 
 def iter_time_ordered(bag: Bag, topics: Optional[Sequence[str]] = None,

@@ -417,21 +417,20 @@ def _run_scenario_partition(scenario: Scenario, source: "str | bytes",
         src = Bag.open_read(source, backend="disk")
     if scenario.use_memory_cache:
         # materialise the (filtered) partition into the ROSBag cache (§3.2):
+        # wholly selected chunks as bytes, cut ones record by record
         tr = otrace.TRACER
         slot = tr.begin("bag.cache_fill", "play") if tr is not None else None
-        cache = Bag.open_write(backend="memory")
-        for msg in src.read_messages(topics=topics, start=t_start,
-                                     end=t_end, chunk_range=chunk_range):
-            cache.write_message(msg)
-        cache.close()
-        cache_image = cache.chunked_file.image()
-        play_bag = Bag.open_read(backend="memory", image=cache_image)
+        fill = src.selection_image(topics=topics, start=t_start, end=t_end,
+                                   chunk_range=chunk_range)
+        play_bag = Bag.open_read(backend="memory", image=fill.image)
         if slot is not None:
-            otrace.Tracer.set_attrs(slot, {"messages": play_bag.num_messages,
-                                           "bytes": len(cache_image)})
+            otrace.Tracer.set_attrs(slot, {
+                "messages": play_bag.num_messages, "bytes": len(fill.image),
+                "raw_chunks": fill.raw_chunks,
+                "decoded_chunks": fill.decoded_chunks})
             otrace.Tracer.end(slot)
         play = dict(chunk_range=None, topics=None, start=None, end=None)
-        input_topics = play_bag.topics
+        input_topics = play_bag.indexed_topics
     else:
         play_bag = src
         play = dict(chunk_range=chunk_range, topics=topics,

@@ -213,14 +213,10 @@ class BagTokenDataset:
         lo, hi = parts[min(rank, len(parts) - 1)]
         if use_memory_cache:
             # materialise this rank's partition into the ROSBag memory cache
-            cache = Bag.open_write(backend="memory")
-            for msg in src.read_messages(chunk_range=(lo, hi)):
-                cache.write_message(msg)
-            cache.close()
+            image = src.selection_image(chunk_range=(lo, hi)).image
             self._records = [
                 decode(m.data)[0] for m in Bag.open_read(
-                    backend="memory",
-                    image=cache.chunked_file.image()).read_messages()]
+                    backend="memory", image=image).read_messages()]
         else:
             self._records = [decode(m.data)[0] for m in
                              src.read_messages(chunk_range=(lo, hi))]

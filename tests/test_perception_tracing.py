@@ -33,12 +33,9 @@ def _bag(tmp_path, n=N_MSGS, payload=640):
 def _cache_image_len(path):
     """Bytes of the in-memory bag cache a partition of the whole bag fills."""
     src = Bag.open_read(path, backend="disk")
-    cache = Bag.open_write(backend="memory")
-    for msg in src.read_messages():
-        cache.write_message(msg)
-    cache.close()
+    n = len(src.selection_image(chunk_range=(0, src.num_chunks)).image)
     src.close()
-    return len(cache.chunked_file.image())
+    return n
 
 
 def _suite(path):
@@ -64,7 +61,11 @@ def test_traced_suite_records_fill_readback_and_close(tmp_path):
 
     (fill,) = by["bag.cache_fill"]
     assert fill[3] == "play"
-    assert fill[8] == {"messages": N_MSGS, "bytes": _cache_image_len(path)}
+    # the whole bag is selected: every chunk is copied as bytes
+    with Bag.open_read(path) as src:
+        n_chunks = src.num_chunks
+    assert fill[8] == {"messages": N_MSGS, "bytes": _cache_image_len(path),
+                       "raw_chunks": n_chunks, "decoded_chunks": 0}
 
     steps, reads = by["logic.step"], by["perception.readback"]
     assert len(steps) == len(reads) == len(by["perception.step"]) \
