@@ -47,7 +47,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
                          (``--check`` gates that exactly the poisoned
                          scenarios + DAG downstream degrade to ERROR and
                          every survivor is bit-identical)
-    roofline_*         — dry-run roofline terms per (arch x shape x mesh)
 """
 
 from __future__ import annotations
@@ -61,12 +60,11 @@ def main() -> None:
     enable_compile_cache()
     print("name,us_per_call,derived")
     from benchmarks import (aggregation, bag_cache, binpipe, chaos,
-                            perception, pipeline, roofline_report,
-                            scalability, scenario_matrix, shm, transport)
+                            perception, pipeline, scalability,
+                            scenario_matrix, shm, transport)
     failures = 0
     for mod in (bag_cache, scalability, scenario_matrix, aggregation,
-                pipeline, transport, shm, perception, binpipe, chaos,
-                roofline_report):
+                pipeline, transport, shm, perception, binpipe, chaos):
         try:
             mod.main(csv=True)
         except Exception:  # noqa: BLE001

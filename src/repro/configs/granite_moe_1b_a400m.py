@@ -1,7 +1,6 @@
 """granite-moe-1b-a400m [moe] — 32 experts top-8
 [hf:ibm-granite/granite-3.0-1b-a400m-base].  24L d_model=1024 16H (GQA kv=8)
-per-expert d_ff=512 vocab=49155.  Small experts => true expert-parallel
-sharding (E over `model`) is the right layout."""
+per-expert d_ff=512 vocab=49155."""
 
 from repro.models.config import ModelConfig, register
 
@@ -17,6 +16,5 @@ register(ModelConfig(
     vocab_size=49155,
     num_experts=32,
     num_experts_per_tok=8,
-    expert_sharding="expert",
     rope_theta=10_000.0,
 ))

@@ -1,6 +1,5 @@
 """grok-1-314b [moe] — 8 experts top-2 [hf:xai-org/grok-1; unverified].
-64L d_model=6144 48H (GQA kv=8) d_ff=32768 vocab=131072.  Huge experts =>
-TP inside experts (d_ff over `model`) + FSDP over `data` to fit HBM."""
+64L d_model=6144 48H (GQA kv=8) d_ff=32768 vocab=131072."""
 
 from repro.models.config import ModelConfig, register
 
@@ -16,6 +15,5 @@ register(ModelConfig(
     vocab_size=131072,
     num_experts=8,
     num_experts_per_tok=2,
-    expert_sharding="ffn",
     rope_theta=10_000.0,
 ))

@@ -1,6 +1,6 @@
 """qwen2-vl-7b [vlm] — M-RoPE, dynamic resolution [arXiv:2409.12191; hf].
 28L d_model=3584 28H (GQA kv=4) d_ff=18944 vocab=152064.
-The vision tower is a STUB: input_specs() feeds precomputed patch
+The vision tower is a stub: the batch feeds precomputed patch
 embeddings (B, S, d_model) + 3D (t,h,w) position ids for M-RoPE."""
 
 from repro.models.config import ModelConfig, register

@@ -1,6 +1,6 @@
 """seamless-m4t-large-v2 [audio] — encoder-decoder [arXiv:2308.11596; hf].
 24L d_model=1024 16H (kv=16) d_ff=8192 vocab=256206.
-The speech frontend is a STUB: input_specs() feeds precomputed frame
+The speech frontend is a stub: the batch feeds precomputed frame
 embeddings; 24 encoder + 24 decoder layers with cross-attention."""
 
 from repro.models.config import ModelConfig, register

@@ -1,8 +1,6 @@
-from .pipeline import (BagTokenDataset, PrefetchIterator,
-                       assemble_message_batch, batch_from_columns,
-                       iter_message_batches, payload_blob, payload_matrix,
-                       synthetic_corpus_bag, write_token_bag)
+from .pipeline import (PrefetchIterator, assemble_message_batch,
+                       batch_from_columns, iter_message_batches, payload_blob,
+                       payload_matrix)
 
-__all__ = ["BagTokenDataset", "PrefetchIterator", "assemble_message_batch",
-           "batch_from_columns", "iter_message_batches", "payload_blob",
-           "payload_matrix", "synthetic_corpus_bag", "write_token_bag"]
+__all__ = ["PrefetchIterator", "assemble_message_batch", "batch_from_columns",
+           "iter_message_batches", "payload_blob", "payload_matrix"]

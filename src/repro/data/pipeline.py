@@ -1,13 +1,9 @@
-"""Training data pipeline on top of the paper's Bag substrate.
+"""Batches of bag records for jitted user logic.
 
-The same recorded-data machinery that replays sensor logs feeds the LM
-training loop: token sequences are stored as Bag records (topic
-``/tokens``, BinPipedRDD uniform format), partitioned by chunk ranges
-across data-parallel ranks, replayed through the ROSBag memory cache, and
-prefetched on a background thread.
-
-This is deliberately the paper's Fig 5 workflow with "User Logic" = the
-training step:   Bag -> (memory cache) -> decode -> batch -> train_step.
+Fixed-layout and columnar batch builders (the BinPipedRDD frame stage,
+shaped for :func:`repro.kernels.sensor_decode.sensor_decode`), message
+batching for the batched replay path, and a background-thread prefetch
+iterator.
 """
 
 from __future__ import annotations
@@ -19,8 +15,7 @@ from typing import Iterator, Optional, Sequence
 
 import numpy as np
 
-from repro.core.bag import Bag, Message, partition_bag
-from repro.core.binpipe import decode, encode
+from repro.core.bag import Message
 
 
 def assemble_message_batch(messages: Sequence[Message], align: int = 128,
@@ -166,80 +161,6 @@ def iter_message_batches(messages: "Iterator[Message] | Sequence[Message]",
     if prefetch > 0:
         return iter(PrefetchIterator(frames(), depth=prefetch))
     return frames()
-
-
-def write_token_bag(path: str, sequences: np.ndarray,
-                    chunk_bytes: int = 256 * 1024) -> str:
-    """sequences: (N, seq_len) int32 -> one Bag record per sequence."""
-    bag = Bag.open_write(path, chunk_bytes=chunk_bytes)
-    for i, seq in enumerate(sequences):
-        bag.write("/tokens", i, encode([np.asarray(seq, np.int32)]))
-    bag.close()
-    return path
-
-
-def synthetic_corpus_bag(path: str, num_sequences: int, seq_len: int,
-                         vocab_size: int, seed: int = 0,
-                         chunk_bytes: int = 8 * 1024) -> str:
-    """Deterministic synthetic corpus with local structure (a noisy
-    integer random walk mod vocab) so a trained model has signal to fit —
-    loss decreasing on this corpus is a meaningful end-to-end check."""
-    rng = np.random.RandomState(seed)
-    start = rng.randint(0, vocab_size, size=(num_sequences, 1))
-    steps = rng.randint(-3, 4, size=(num_sequences, seq_len + 1))
-    seqs = np.cumsum(np.concatenate([start, steps], axis=1), axis=1)
-    seqs = np.mod(seqs[:, :seq_len + 1], vocab_size).astype(np.int32)
-    return write_token_bag(path, seqs, chunk_bytes=chunk_bytes)
-
-
-class BagTokenDataset:
-    """Sharded, epoch-shuffled batches out of a token bag.
-
-    ``rank``/``world`` select this worker's chunk-range partition (the same
-    ``partition_bag`` the simulation scheduler uses).  Sequences of length
-    ``seq_len + 1`` become (tokens, labels) shifted pairs.
-    """
-
-    def __init__(self, path: str, batch_size: int, rank: int = 0,
-                 world: int = 1, use_memory_cache: bool = True,
-                 seed: int = 0):
-        self.path = path
-        self.batch_size = batch_size
-        self.rank = rank
-        self.world = world
-        self.seed = seed
-        src = Bag.open_read(path)
-        parts = partition_bag(src, world)
-        lo, hi = parts[min(rank, len(parts) - 1)]
-        if use_memory_cache:
-            # materialise this rank's partition into the ROSBag memory cache
-            image = src.selection_image(chunk_range=(lo, hi)).image
-            self._records = [
-                decode(m.data)[0] for m in Bag.open_read(
-                    backend="memory", image=image).read_messages()]
-        else:
-            self._records = [decode(m.data)[0] for m in
-                             src.read_messages(chunk_range=(lo, hi))]
-        src.close()
-        if not self._records:
-            raise ValueError(f"rank {rank}: empty partition")
-
-    def __len__(self) -> int:
-        return len(self._records)
-
-    def batches(self, epochs: Optional[int] = None) -> Iterator[dict]:
-        epoch = 0
-        n = len(self._records)
-        while epochs is None or epoch < epochs:
-            order = np.random.RandomState(
-                self.seed + epoch).permutation(n)
-            for i in range(0, n - self.batch_size + 1, self.batch_size):
-                rows = [self._records[j]
-                        for j in order[i:i + self.batch_size]]
-                arr = np.stack(rows)                    # (B, seq_len + 1)
-                yield {"tokens": arr[:, :-1].astype(np.int32),
-                       "labels": arr[:, 1:].astype(np.int32)}
-            epoch += 1
 
 
 class PrefetchIterator:

@@ -47,4 +47,11 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     return jax.default_backend() != "tpu"
 
 
-__all__ = ["INTERPRET_ENV", "resolve_interpret"]
+def compiled_for_tpu() -> bool:
+    """Whether the kernels compile for a TPU (:func:`resolve_interpret`
+    resolves to compiled): the rule by which the model layers run a Pallas
+    kernel in place of their jnp path."""
+    return not resolve_interpret(None)
+
+
+__all__ = ["INTERPRET_ENV", "compiled_for_tpu", "resolve_interpret"]

@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-from typing import Any, Callable, NamedTuple
+from typing import Callable, NamedTuple
 
 from . import encdec, transformer
 from .config import ModelConfig, get_config, list_configs, register
@@ -11,12 +11,8 @@ from .config import ModelConfig, get_config, list_configs, register
 class ModelApi(NamedTuple):
     cfg: ModelConfig
     init_params: Callable
-    param_specs: Callable
     forward: Callable
     loss_fn: Callable
-    prefill: Callable
-    decode_step: Callable
-    init_decode_state: Callable
 
 
 def get_model(cfg: ModelConfig) -> ModelApi:
@@ -24,15 +20,8 @@ def get_model(cfg: ModelConfig) -> ModelApi:
     return ModelApi(
         cfg=cfg,
         init_params=lambda key, dtype=None: mod.init_params(cfg, key, dtype),
-        param_specs=lambda: mod.param_specs(cfg),
         forward=lambda params, batch: mod.forward(cfg, params, batch),
         loss_fn=lambda params, batch: mod.loss_fn(cfg, params, batch),
-        prefill=lambda params, batch, s_max: mod.prefill(
-            cfg, params, batch, s_max),
-        decode_step=lambda params, state, tokens: mod.decode_step(
-            cfg, params, state, tokens),
-        init_decode_state=lambda *a, **kw: mod.init_decode_state(
-            cfg, *a, **kw),
     )
 
 

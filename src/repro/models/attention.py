@@ -1,30 +1,26 @@
 """Attention variants: GQA (+ qk-norm / QKV-bias / sliding-window / M-RoPE)
-and MLA (multi-head latent attention, compressed KV cache + absorbed decode).
+and MLA (multi-head latent attention).
 
 Sequence-level attention is a memory-bounded chunked online-softmax
 ("flash-style") implementation in pure jnp, :func:`chunked_attention`.
 :func:`gqa_forward` runs the fused Pallas kernel of
 ``repro.kernels.flash_attention`` in its place where that kernel is
-compiled for a TPU (``kernels.compat.resolve_interpret(None)`` is False),
-no mesh over more than one device is active (a ``pallas_call`` is not
-partitioned) and each kv head's query heads fill whole lane tiles
-(``flash_attention.lane_tiled``); its gradient is the VJP of
-:func:`chunked_attention`.  Decode, MLA and cross-attention always take
-the jnp path.  The core of both paths runs under the ``attention_core``
-scope, and the ``attention`` metrics scope counts each trace of either
-path (counters ``fused``, ``chunked``).
+compiled for a TPU (``kernels.compat.compiled_for_tpu``) and each kv
+head's query heads fill whole lane tiles (``flash_attention.lane_tiled``);
+its gradient is the VJP of :func:`chunked_attention`.  MLA and
+cross-attention always take the jnp path.  The core of both paths runs
+under the ``attention_core`` scope, and the ``attention`` metrics scope
+counts each trace of either path (counters ``fused``, ``chunked``).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import context as ctx
-from repro.kernels.compat import resolve_interpret
+from repro.kernels import compat
 from repro.kernels.flash_attention import flash_attention, lane_tiled
 from repro.obs import metrics as obs_metrics
 
@@ -33,21 +29,11 @@ from .layers import ParamDef, apply_mrope, apply_rope, rms_norm
 
 NEG_INF = -1e30
 
+#: kv positions a step of :func:`chunked_attention` sweeps
+CHUNK = 512
+
 #: which attention path each trace of :func:`gqa_forward` took
 _PATHS = obs_metrics.scope("attention")
-
-
-class KVCache(NamedTuple):
-    """Dense KV cache (GQA): k/v (B, S_max, KV, hd); index = #valid tokens."""
-    k: jax.Array
-    v: jax.Array
-
-
-class MLACache(NamedTuple):
-    """Compressed cache (MLA): latent (B, S_max, kv_lora), rope key
-    (B, S_max, qk_rope) — the point of MLA is that this is ~10x smaller."""
-    latent: jax.Array
-    k_rope: jax.Array
 
 
 # ==========================================================================
@@ -57,18 +43,18 @@ class MLACache(NamedTuple):
 def gqa_table(cfg: ModelConfig) -> dict[str, ParamDef]:
     D, H, KV, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     t = {
-        "wq": ParamDef((D, H * hd), ("embed", "heads")),
-        "wk": ParamDef((D, KV * hd), ("embed", "kv_heads")),
-        "wv": ParamDef((D, KV * hd), ("embed", "kv_heads")),
-        "wo": ParamDef((H * hd, D), ("heads", "embed")),
+        "wq": ParamDef((D, H * hd)),
+        "wk": ParamDef((D, KV * hd)),
+        "wv": ParamDef((D, KV * hd)),
+        "wo": ParamDef((H * hd, D)),
     }
     if cfg.qkv_bias:
-        t["bq"] = ParamDef((H * hd,), ("heads",), init="zeros")
-        t["bk"] = ParamDef((KV * hd,), ("kv_heads",), init="zeros")
-        t["bv"] = ParamDef((KV * hd,), ("kv_heads",), init="zeros")
+        t["bq"] = ParamDef((H * hd,), init="zeros")
+        t["bk"] = ParamDef((KV * hd,), init="zeros")
+        t["bv"] = ParamDef((KV * hd,), init="zeros")
     if cfg.qk_norm:
-        t["q_norm"] = ParamDef((hd,), (None,), init="ones")
-        t["k_norm"] = ParamDef((hd,), (None,), init="ones")
+        t["q_norm"] = ParamDef((hd,), init="ones")
+        t["k_norm"] = ParamDef((hd,), init="ones")
     return t
 
 
@@ -99,14 +85,12 @@ def _project_qkv(cfg: ModelConfig, p: dict, x: jax.Array, positions):
 
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool = True, window: int = 0,
-                      chunk: int = 512, kv_valid: Optional[jax.Array] = None,
-                      unroll: bool = False) -> jax.Array:
+                      chunk: int = CHUNK) -> jax.Array:
     """Online-softmax attention, scanned over KV chunks.
 
     q: (B, Sq, H, hd);  k, v: (B, Sk, KV, hd) with H % KV == 0.
     ``causal`` masks j > i (+ Sk - Sq offset); ``window`` > 0 additionally
-    masks j <= i - window (sliding window).  ``kv_valid``: (B,) number of
-    valid kv positions (for padded caches).  Returns (B, Sq, H, vd).
+    masks j <= i - window (sliding window).  Returns (B, Sq, H, vd).
     """
     B, Sq, H, hd = q.shape
     _, Sk, KV, vd = v.shape
@@ -134,9 +118,6 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         if window:
             mask &= kv_pos[None, :] > q_pos[:, None] - window
         mask &= (kv_pos < Sk)[None, :]
-        if kv_valid is not None:
-            bmask = kv_pos[None, :] < kv_valid[:, None]   # (B, C)
-            s = jnp.where(bmask[:, None, None, None, :], s, NEG_INF)
         s = jnp.where(mask[None, None, None, :, :], s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p_ = jnp.exp(s - m_new[..., None])
@@ -149,23 +130,16 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     m0 = jnp.full((B, KV, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, KV, G, Sq), jnp.float32)
     a0 = jnp.zeros((B, KV, G, Sq, vd), jnp.float32)
-    if unroll:
-        # python loop: identical math, loop body visible to cost_analysis
-        carry = (m0, l0, a0)
-        for j in range(nchunks):
-            carry, _ = step(carry, (jnp.int32(j), kc[:, j], vc[:, j]))
-        m, l, acc = carry
-    else:
-        # checkpoint the chunk body: without this the backward pass stores
-        # every chunk's (blk_q x blk_k) score tile in f32 — O(S^2) memory,
-        # exactly what flash attention exists to avoid.  With it, backward
-        # recomputes scores per chunk from q/k/v (the flash backward).
-        step_ckpt = jax.checkpoint(
-            step, policy=jax.checkpoint_policies.nothing_saveable)
-        (m, l, acc), _ = jax.lax.scan(
-            step_ckpt, (m0, l0, a0),
-            (jnp.arange(nchunks), jnp.moveaxis(kc, 1, 0),
-             jnp.moveaxis(vc, 1, 0)))
+    # checkpoint the chunk body: without this the backward pass stores
+    # every chunk's (blk_q x blk_k) score tile in f32 — O(S^2) memory,
+    # exactly what flash attention exists to avoid.  With it, backward
+    # recomputes scores per chunk from q/k/v (the flash backward).
+    step_ckpt = jax.checkpoint(
+        step, policy=jax.checkpoint_policies.nothing_saveable)
+    (m, l, acc), _ = jax.lax.scan(
+        step_ckpt, (m0, l0, a0),
+        (jnp.arange(nchunks), jnp.moveaxis(kc, 1, 0),
+         jnp.moveaxis(vc, 1, 0)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     out = jnp.moveaxis(out, 3, 1).reshape(B, Sq, H, vd)   # b k g q d -> b q (kg) d
     return out.astype(q.dtype)
@@ -197,86 +171,22 @@ def _fused_bwd(causal, window, chunk, res, g):
 fused_attention.defvjp(_fused_fwd, _fused_bwd)
 
 
-def _takes_kernel(cfg: ModelConfig) -> bool:
-    """Whether :func:`gqa_forward` runs the Pallas kernel: compiled for a
-    TPU, on one device, with output columns the kernel can tile."""
-    mesh = ctx.mesh_ctx()
-    return (not resolve_interpret(None)
-            and lane_tiled(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim)
-            and (mesh is None or mesh.devices.size == 1))
-
-
 def gqa_forward(cfg: ModelConfig, p: dict, x: jax.Array,
-                positions: jax.Array, causal: bool = True,
-                ) -> tuple[jax.Array, KVCache]:
-    """Full-sequence (train / prefill). Returns output and the KV to cache."""
+                positions: jax.Array, causal: bool = True) -> jax.Array:
+    """Full-sequence GQA: (B, S, D) -> (B, S, D)."""
     q, k, v = _project_qkv(cfg, p, x, positions)
-    if cfg.seq_sharded_attention:
-        # queries/outputs seq-sharded over `model`; K/V replicated across
-        # the model axis instead of the (B,S,H*hd) activations
-        q = ctx.constrain(q, ctx.dp(), "model", None, None)
-    chunk = cfg.attn_chunk if cfg.attn_chunk > 0 else k.shape[1]
-    if _takes_kernel(cfg):
+    if (compat.compiled_for_tpu()
+            and lane_tiled(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim)):
         _PATHS.counter("fused").inc()
-        out = fused_attention(q, k, v, causal, cfg.sliding_window, chunk)
+        out = fused_attention(q, k, v, causal, cfg.sliding_window, CHUNK)
     else:
         _PATHS.counter("chunked").inc()
         with jax.named_scope("attention_core"):
             out = chunked_attention(q, k, v, causal=causal,
-                                    window=cfg.sliding_window, chunk=chunk,
-                                    unroll=cfg.unroll_inner)
-    if cfg.seq_sharded_attention:
-        out = ctx.constrain(out, ctx.dp(), "model", None, None)
+                                    window=cfg.sliding_window)
     B, S, H, hd = q.shape
-    out = jnp.einsum("bsh,hd->bsd", out.reshape(B, S, H * hd),
-                     p["wo"].astype(x.dtype))
-    return out, KVCache(k, v)
-
-
-def gqa_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache: KVCache,
-               index: jax.Array) -> tuple[jax.Array, KVCache]:
-    """One-token decode. x: (B, 1, D); cache k/v: (B, S_max, KV, hd);
-    index: scalar int32 — number of tokens already in the cache."""
-    B = x.shape[0]
-    positions = jnp.full((B, 1), index, jnp.int32)
-    if cfg.rope_type == "mrope":       # text-only decode: t=h=w=index
-        positions = jnp.full((B, 1, 3), index, jnp.int32)
-    q, k, v = _project_qkv(cfg, p, x, positions)
-    S_max = cache.k.shape[1]
-    ring = bool(cfg.sliding_window) and S_max <= cfg.sliding_window
-    write_at = jnp.mod(index, S_max) if ring else index
-    k_cache = jax.lax.dynamic_update_slice_in_dim(cache.k, k, write_at, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(cache.v, v, write_at, axis=1)
-    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    G = H // KV
-    qg = q.reshape(B, 1, KV, G, hd)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qg.astype(jnp.float32),
-                   k_cache.astype(jnp.float32)) * hd ** -0.5
-    kv_pos = jnp.arange(S_max)
-    if ring:
-        # ring buffer holds exactly the last S_max(=window) positions; the
-        # only invalid slots are the not-yet-written ones before wraparound
-        valid = kv_pos <= index
-    else:
-        valid = kv_pos <= index
-        if cfg.sliding_window:
-            valid &= kv_pos > index - cfg.sliding_window
-    s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgqs,bskd->bkgqd", w, v_cache.astype(jnp.float32))
-    out = jnp.moveaxis(out, 3, 1).reshape(B, 1, H * hd).astype(x.dtype)
-    out = jnp.einsum("bsh,hd->bsd", out, p["wo"].astype(x.dtype))
-    return out, KVCache(k_cache, v_cache)
-
-
-def gqa_empty_cache(cfg: ModelConfig, batch: int, s_max: int,
-                    dtype) -> KVCache:
-    KV, hd = cfg.num_kv_heads, cfg.head_dim
-    if cfg.sliding_window:
-        # ring buffer: exactly `window` slots (see gqa_decode)
-        s_max = min(s_max, cfg.sliding_window)
-    return KVCache(jnp.zeros((batch, s_max, KV, hd), dtype),
-                   jnp.zeros((batch, s_max, KV, hd), dtype))
+    return jnp.einsum("bsh,hd->bsd", out.reshape(B, S, H * hd),
+                      p["wo"].astype(x.dtype))
 
 
 # ==========================================================================
@@ -288,18 +198,18 @@ def mla_table(cfg: ModelConfig) -> dict[str, ParamDef]:
     nope, rope_d, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     qlr, kvlr = cfg.q_lora_rank, cfg.kv_lora_rank
     t = {
-        "kv_down": ParamDef((D, kvlr + rope_d), ("embed", "latent")),
-        "kv_norm": ParamDef((kvlr,), (None,), init="ones"),
-        "kv_up_k": ParamDef((kvlr, H * nope), ("latent", "heads")),
-        "kv_up_v": ParamDef((kvlr, H * vd), ("latent", "heads")),
-        "wo": ParamDef((H * vd, D), ("heads", "embed")),
+        "kv_down": ParamDef((D, kvlr + rope_d)),
+        "kv_norm": ParamDef((kvlr,), init="ones"),
+        "kv_up_k": ParamDef((kvlr, H * nope)),
+        "kv_up_v": ParamDef((kvlr, H * vd)),
+        "wo": ParamDef((H * vd, D)),
     }
     if qlr:
-        t["q_down"] = ParamDef((D, qlr), ("embed", "latent"))
-        t["q_norm"] = ParamDef((qlr,), (None,), init="ones")
-        t["q_up"] = ParamDef((qlr, H * (nope + rope_d)), ("latent", "heads"))
+        t["q_down"] = ParamDef((D, qlr))
+        t["q_norm"] = ParamDef((qlr,), init="ones")
+        t["q_up"] = ParamDef((qlr, H * (nope + rope_d)))
     else:
-        t["wq"] = ParamDef((D, H * (nope + rope_d)), ("embed", "heads"))
+        t["wq"] = ParamDef((D, H * (nope + rope_d)))
     return t
 
 
@@ -331,7 +241,7 @@ def _mla_latent(cfg: ModelConfig, p: dict, x: jax.Array, positions):
 
 
 def mla_forward(cfg: ModelConfig, p: dict, x: jax.Array,
-                positions: jax.Array) -> tuple[jax.Array, MLACache]:
+                positions: jax.Array) -> jax.Array:
     """Full-sequence MLA (non-absorbed: expand latent, run chunked attn)."""
     B, S, _ = x.shape
     H = cfg.num_heads
@@ -346,52 +256,6 @@ def mla_forward(cfg: ModelConfig, p: dict, x: jax.Array,
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, rope_d))],
         axis=-1)
-    chunk = cfg.attn_chunk if cfg.attn_chunk > 0 else S
-    out = chunked_attention(q, k, v, causal=True, chunk=chunk,
-                            unroll=cfg.unroll_inner)
-    out = jnp.einsum("bsh,hd->bsd", out.reshape(B, S, H * vd),
-                     p["wo"].astype(x.dtype))
-    return out, MLACache(latent, k_rope)
-
-
-def mla_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache: MLACache,
-               index: jax.Array) -> tuple[jax.Array, MLACache]:
-    """Absorbed one-token decode: queries are mapped into latent space, so
-    attention runs against the *compressed* cache directly — the MLA trick
-    that makes the 500k-class caches feasible memory-wise."""
-    B = x.shape[0]
-    H = cfg.num_heads
-    nope, rope_d, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    kvlr = cfg.kv_lora_rank
-    positions = jnp.full((B, 1), index, jnp.int32)
-    q_nope, q_rope = _mla_q(cfg, p, x, positions)       # (B,1,H,·)
-    latent_t, k_rope_t = _mla_latent(cfg, p, x, positions)
-    latent = jax.lax.dynamic_update_slice_in_dim(
-        cache.latent, latent_t.astype(cache.latent.dtype), index, axis=1)
-    k_rope = jax.lax.dynamic_update_slice_in_dim(
-        cache.k_rope, k_rope_t.astype(cache.k_rope.dtype), index, axis=1)
-    # absorb kv_up_k into q:  (B,1,H,nope) @ (kvlr,H,nope) -> (B,1,H,kvlr)
-    up_k = p["kv_up_k"].astype(x.dtype).reshape(kvlr, H, nope)
-    q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, up_k)
-    s = (jnp.einsum("bqhr,bsr->bhqs", q_lat.astype(jnp.float32),
-                    latent.astype(jnp.float32))
-         + jnp.einsum("bqhr,bsr->bhqs", q_rope.astype(jnp.float32),
-                      k_rope.astype(jnp.float32))) * (nope + rope_d) ** -0.5
-    S_max = latent.shape[1]
-    valid = jnp.arange(S_max) <= index
-    s = jnp.where(valid[None, None, None, :], s, NEG_INF)
-    w = jax.nn.softmax(s, axis=-1)
-    out_lat = jnp.einsum("bhqs,bsr->bqhr", w,
-                         latent.astype(jnp.float32)).astype(x.dtype)
-    up_v = p["kv_up_v"].astype(x.dtype).reshape(kvlr, H, vd)
-    out = jnp.einsum("bqhr,rhv->bqhv", out_lat, up_v)
-    out = jnp.einsum("bsh,hd->bsd", out.reshape(B, 1, H * vd),
-                     p["wo"].astype(x.dtype))
-    return out, MLACache(latent, k_rope)
-
-
-def mla_empty_cache(cfg: ModelConfig, batch: int, s_max: int,
-                    dtype) -> MLACache:
-    return MLACache(
-        jnp.zeros((batch, s_max, cfg.kv_lora_rank), dtype),
-        jnp.zeros((batch, s_max, cfg.qk_rope_head_dim), dtype))
+    out = chunked_attention(q, k, v, causal=True)
+    return jnp.einsum("bsh,hd->bsd", out.reshape(B, S, H * vd),
+                      p["wo"].astype(x.dtype))

@@ -10,8 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from dataclasses import dataclass, field
-from typing import Optional
+from dataclasses import dataclass
 
 
 @dataclass(frozen=True)
@@ -76,27 +75,11 @@ class ModelConfig:
     dtype: str = "bfloat16"          # forward compute/param dtype
     tie_embeddings: bool = False
 
-    # distribution knobs (consumed by repro.distributed.sharding)
-    expert_sharding: str = "ffn"     # "ffn" (TP over d_ff) | "expert" (EP over E)
+    # per-block rematerialisation of the layer stack
     remat: str = "full"              # none | block | full
-    scan_layers: bool = True
-    # inner-scan tile sizes; 0 = unrolled/full (used by the dry-run flop
-    # calibration probes, where while-loop bodies are cost-counted once)
-    attn_chunk: int = 512
+    # time steps a block of the jnp selective scan covers; 0 = the whole
+    # sequence
     ssm_block: int = 256
-    unroll_inner: bool = False       # python-loop inner chunks (probes)
-    # beyond-paper perf knobs (see EXPERIMENTS.md §Perf):
-    # shard the residual stream's seq dim over `model` at block boundaries
-    # (Megatron-style sequence parallelism: 16x smaller remat stacks for
-    # an all-gather + reduce-scatter per layer)
-    seq_sharded_residual: bool = False
-    # shard attention queries/outputs over seq when heads don't divide the
-    # model axis (avoids replicating (B,S,H*hd) activations)
-    seq_sharded_attention: bool = False
-    # run the selective-scan decay/state intermediates in bf16 (the Pallas
-    # kernel's VMEM-resident state makes this moot on TPU; in the jnp path
-    # it halves the dominant (B,blk,di,N) HBM traffic at ~1e-2 rel error)
-    ssm_bf16: bool = False
 
     def __post_init__(self):
         if self.rope_type not in ("rope", "mrope", "none"):
@@ -122,8 +105,8 @@ class ModelConfig:
 
     @property
     def padded_vocab(self) -> int:
-        """Vocab padded to 256 (Megatron-style) so the vocab axis shards
-        evenly over `model`; the loss masks the padding columns."""
+        """Vocab padded to a multiple of 256 (Megatron-style); the loss
+        masks the padding columns."""
         return -(-self.vocab_size // 256) * 256
 
     @property
@@ -161,11 +144,6 @@ class ModelConfig:
         if kind == "attention":
             return self.replace(family="dense", **flat)
         return self.replace(family="ssm", attention="none", **flat)
-
-    @property
-    def sub_quadratic(self) -> bool:
-        """Whether the arch can run the long_500k cell (see DESIGN.md)."""
-        return self.family in ("ssm", "hybrid")
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

@@ -1,34 +1,30 @@
 """Encoder–decoder transformer (seamless-m4t-large-v2 backbone).
 
-The speech frontend is a STUB per the assignment: ``input_specs()`` feeds
-precomputed frame embeddings (B, S_enc, D).  The encoder is a bidirectional
-transformer over frames; the decoder is a causal LM with cross-attention.
-Decode shapes exercise the decoder with cached encoder output + self KV.
+The speech frontend is a stub: the batch feeds precomputed frame
+embeddings (B, S_enc, D).  The encoder is a bidirectional transformer over
+frames; the decoder is a causal LM with cross-attention.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import context as ctx
-
 from . import attention as attn
 from .config import ModelConfig
 from .layers import (ParamDef, embed_table, embed_tokens, init_table,
-                     lm_logits, mlp_forward, mlp_table, rms_norm, table_specs)
+                     lm_logits, mlp_forward, mlp_table, rms_norm)
 
 
 def cross_table(cfg: ModelConfig) -> dict[str, ParamDef]:
     D, H, KV, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     return {
-        "wq": ParamDef((D, H * hd), ("embed", "heads")),
-        "wk": ParamDef((D, KV * hd), ("embed", "kv_heads")),
-        "wv": ParamDef((D, KV * hd), ("embed", "kv_heads")),
-        "wo": ParamDef((H * hd, D), ("heads", "embed")),
+        "wq": ParamDef((D, H * hd)),
+        "wk": ParamDef((D, KV * hd)),
+        "wv": ParamDef((D, KV * hd)),
+        "wo": ParamDef((H * hd, D)),
     }
 
 
@@ -47,9 +43,7 @@ def cross_forward(cfg: ModelConfig, p: dict, x: jax.Array, k, v) -> jax.Array:
     H, hd = cfg.num_heads, cfg.head_dim
     q = jnp.einsum("bsd,dh->bsh", x,
                    p["wq"].astype(x.dtype)).reshape(B, S, H, hd)
-    chunk = cfg.attn_chunk if cfg.attn_chunk > 0 else k.shape[1]
-    out = attn.chunked_attention(q, k, v, causal=False, chunk=chunk,
-                                 unroll=cfg.unroll_inner)
+    out = attn.chunked_attention(q, k, v, causal=False)
     return jnp.einsum("bsh,hd->bsd", out.reshape(B, S, H * hd),
                       p["wo"].astype(x.dtype))
 
@@ -60,17 +54,16 @@ def enc_block_tables(cfg: ModelConfig) -> dict:
     D = cfg.d_model
     return {
         "attn": attn.gqa_table(cfg),
-        "norm_attn": {"scale": ParamDef((D,), ("embed",), init="ones")},
+        "norm_attn": {"scale": ParamDef((D,), init="ones")},
         "mlp": mlp_table(D, cfg.d_ff),
-        "norm_mlp": {"scale": ParamDef((D,), ("embed",), init="ones")},
+        "norm_mlp": {"scale": ParamDef((D,), init="ones")},
     }
 
 
 def dec_block_tables(cfg: ModelConfig) -> dict:
     t = enc_block_tables(cfg)
     t["cross"] = cross_table(cfg)
-    t["norm_cross"] = {"scale": ParamDef((cfg.d_model,), ("embed",),
-                                         init="ones")}
+    t["norm_cross"] = {"scale": ParamDef((cfg.d_model,), init="ones")}
     return t
 
 
@@ -92,61 +85,30 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> dict:
     }
     enc_keys = jax.random.split(k_enc, cfg.num_encoder_layers)
     dec_keys = jax.random.split(k_dec, cfg.num_layers)
-    if cfg.scan_layers:
-        params["enc_layers"] = jax.vmap(
-            lambda k: _init_block(enc_block_tables(cfg), k, dtype))(enc_keys)
-        params["dec_layers"] = jax.vmap(
-            lambda k: _init_block(dec_block_tables(cfg), k, dtype))(dec_keys)
-    else:
-        params["enc_layers"] = [_init_block(enc_block_tables(cfg), k, dtype)
-                                for k in enc_keys]
-        params["dec_layers"] = [_init_block(dec_block_tables(cfg), k, dtype)
-                                for k in dec_keys]
+    params["enc_layers"] = jax.vmap(
+        lambda k: _init_block(enc_block_tables(cfg), k, dtype))(enc_keys)
+    params["dec_layers"] = jax.vmap(
+        lambda k: _init_block(dec_block_tables(cfg), k, dtype))(dec_keys)
     return params
-
-
-def param_specs(cfg: ModelConfig) -> dict:
-    lead = ("layers",) if cfg.scan_layers else ()
-
-    def specs(tables):
-        one = {name: {pn: lead + tuple(ax)
-                      for pn, ax in table_specs(tbl).items()}
-               for name, tbl in tables.items()}
-        return one
-    enc = specs(enc_block_tables(cfg))
-    dec = specs(dec_block_tables(cfg))
-    return {
-        "embed": table_specs(
-            embed_table(cfg.padded_vocab, cfg.d_model, cfg.tie_embeddings)),
-        "enc_norm": {"scale": ("embed",)},
-        "enc_layers": enc if cfg.scan_layers
-        else [enc for _ in range(cfg.num_encoder_layers)],
-        "dec_layers": dec if cfg.scan_layers
-        else [dec for _ in range(cfg.num_layers)],
-    }
 
 
 # -- forward ------------------------------------------------------------------
 
 def _enc_block(cfg, p, x, positions):
-    x = ctx.constrain(x, ctx.dp(), None, None)
     h = rms_norm(x, p["norm_attn"]["scale"], cfg.norm_eps)
-    a, _ = attn.gqa_forward(cfg, p["attn"], h, positions, causal=False)
-    x = x + a
+    x = x + attn.gqa_forward(cfg, p["attn"], h, positions, causal=False)
     h = rms_norm(x, p["norm_mlp"]["scale"], cfg.norm_eps)
     return x + mlp_forward(p["mlp"], h, cfg.act)
 
 
 def _dec_block(cfg, p, x, positions, enc_out):
-    x = ctx.constrain(x, ctx.dp(), None, None)
     h = rms_norm(x, p["norm_attn"]["scale"], cfg.norm_eps)
-    a, kv = attn.gqa_forward(cfg, p["attn"], h, positions, causal=True)
-    x = x + a
+    x = x + attn.gqa_forward(cfg, p["attn"], h, positions, causal=True)
     h = rms_norm(x, p["norm_cross"]["scale"], cfg.norm_eps)
     ck, cv = _cross_kv(cfg, p["cross"], enc_out)
     x = x + cross_forward(cfg, p["cross"], h, ck, cv)
     h = rms_norm(x, p["norm_mlp"]["scale"], cfg.norm_eps)
-    return x + mlp_forward(p["mlp"], h, cfg.act), kv
+    return x + mlp_forward(p["mlp"], h, cfg.act)
 
 
 def encode(cfg: ModelConfig, params: dict, frames: jax.Array) -> jax.Array:
@@ -156,14 +118,11 @@ def encode(cfg: ModelConfig, params: dict, frames: jax.Array) -> jax.Array:
     if cfg.remat != "none":
         block = jax.checkpoint(block)
 
-    x = frames.astype(jnp.dtype(cfg.dtype))
-    if cfg.scan_layers:
-        def body(h, lp):
-            return block(lp, h, positions), None
-        x, _ = jax.lax.scan(body, x, params["enc_layers"])
-    else:
-        for lp in params["enc_layers"]:
-            x = block(lp, x, positions)
+    def body(h, lp):
+        return block(lp, h, positions), None
+
+    x, _ = jax.lax.scan(body, frames.astype(jnp.dtype(cfg.dtype)),
+                        params["enc_layers"])
     return rms_norm(x, params["enc_norm"]["scale"], cfg.norm_eps)
 
 
@@ -179,14 +138,10 @@ def forward(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
     if cfg.remat != "none":
         block = jax.checkpoint(block)
 
-    if cfg.scan_layers:
-        def body(h, lp):
-            h2, _ = block(lp, h, positions, enc_out)
-            return h2, None
-        x, _ = jax.lax.scan(body, x, params["dec_layers"])
-    else:
-        for lp in params["dec_layers"]:
-            x, _ = block(lp, x, positions, enc_out)
+    def body(h, lp):
+        return block(lp, h, positions, enc_out), None
+
+    x, _ = jax.lax.scan(body, x, params["dec_layers"])
     x = rms_norm(x, params["embed"]["final_norm"], cfg.norm_eps)
     return lm_logits(params["embed"], x, cfg.tie_embeddings)
 
@@ -196,100 +151,3 @@ def loss_fn(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
     logits = forward(cfg, params, batch)
     return cross_entropy(logits, batch["labels"], batch.get("loss_mask"),
                          real_vocab=cfg.vocab_size)
-
-
-# -- serving -----------------------------------------------------------------
-
-class EncDecState(NamedTuple):
-    self_kv: Any          # (L, B, s_max, KV, hd) x2
-    cross_k: jax.Array    # (L, B, Se, KV, hd)
-    cross_v: jax.Array
-    index: jax.Array
-    last_logits: jax.Array
-
-
-def prefill(cfg: ModelConfig, params: dict, batch: dict,
-            s_max: int) -> EncDecState:
-    enc_out = encode(cfg, params, batch["frames"])
-
-    if cfg.scan_layers:
-        def kv_body(_, lp):
-            return None, _cross_kv(cfg, lp["cross"], enc_out)
-        _, (ck, cv) = jax.lax.scan(kv_body, None, params["dec_layers"])
-    else:
-        pairs = [_cross_kv(cfg, lp["cross"], enc_out)
-                 for lp in params["dec_layers"]]
-        ck = [c for c, _ in pairs]
-        cv = [v for _, v in pairs]
-    B = enc_out.shape[0]
-    KV, hd = cfg.num_kv_heads, cfg.head_dim
-    L = cfg.num_layers
-    dt = jnp.dtype(cfg.dtype)
-    if cfg.scan_layers:
-        kv = attn.KVCache(jnp.zeros((L, B, s_max, KV, hd), dt),
-                          jnp.zeros((L, B, s_max, KV, hd), dt))
-    else:
-        kv = [attn.KVCache(jnp.zeros((B, s_max, KV, hd), dt),
-                           jnp.zeros((B, s_max, KV, hd), dt))
-              for _ in range(L)]
-    logits = jnp.zeros((B, 1, cfg.padded_vocab), jnp.float32)
-    return EncDecState(kv, ck, cv, jnp.int32(0), logits)
-
-
-def init_decode_state(cfg: ModelConfig, batch: int, s_enc: int, s_max: int,
-                      index: int = 0) -> EncDecState:
-    """Decode-only entry (benchmark cells): encoder output already cached."""
-    dtype = jnp.dtype(cfg.dtype)
-    KV, hd, L = cfg.num_kv_heads, cfg.head_dim, cfg.num_layers
-    if cfg.scan_layers:
-        kv = attn.KVCache(jnp.zeros((L, batch, s_max, KV, hd), dtype),
-                          jnp.zeros((L, batch, s_max, KV, hd), dtype))
-        ck = jnp.zeros((L, batch, s_enc, KV, hd), dtype)
-        cv = jnp.zeros((L, batch, s_enc, KV, hd), dtype)
-    else:
-        kv = [attn.KVCache(jnp.zeros((batch, s_max, KV, hd), dtype),
-                           jnp.zeros((batch, s_max, KV, hd), dtype))
-              for _ in range(L)]
-        ck = [jnp.zeros((batch, s_enc, KV, hd), dtype) for _ in range(L)]
-        cv = [jnp.zeros((batch, s_enc, KV, hd), dtype) for _ in range(L)]
-    logits = jnp.zeros((batch, 1, cfg.padded_vocab), jnp.float32)
-    return EncDecState(kv, ck, cv, jnp.int32(index), logits)
-
-
-def decode_step(cfg: ModelConfig, params: dict, state: EncDecState,
-                tokens: jax.Array) -> EncDecState:
-    dtype = jnp.dtype(cfg.dtype)
-    x = embed_tokens(params["embed"], tokens, dtype)
-    index = state.index
-    B = x.shape[0]
-    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-
-    def body(h, lp_cache):
-        lp, kv, ck, cv = lp_cache
-        hh = rms_norm(h, lp["norm_attn"]["scale"], cfg.norm_eps)
-        a, new_kv = attn.gqa_decode(cfg, lp["attn"], hh, kv, index)
-        h = h + a
-        hh = rms_norm(h, lp["norm_cross"]["scale"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dh->bsh", hh,
-                       lp["cross"]["wq"].astype(dtype)).reshape(B, 1, H, hd)
-        co = attn.chunked_attention(q, ck, cv, causal=False)
-        h = h + jnp.einsum("bsh,hd->bsd", co.reshape(B, 1, H * hd),
-                           lp["cross"]["wo"].astype(dtype))
-        hh = rms_norm(h, lp["norm_mlp"]["scale"], cfg.norm_eps)
-        h = h + mlp_forward(lp["mlp"], hh, cfg.act)
-        return h, new_kv
-
-    if cfg.scan_layers:
-        x, new_kv = jax.lax.scan(
-            body, x, (params["dec_layers"], state.self_kv,
-                      state.cross_k, state.cross_v))
-    else:
-        new_kv = []
-        for lp, kv, ck, cv in zip(params["dec_layers"], state.self_kv,
-                                  state.cross_k, state.cross_v):
-            x, nk = body(x, (lp, kv, ck, cv))
-            new_kv.append(nk)
-    x = rms_norm(x, params["embed"]["final_norm"], cfg.norm_eps)
-    logits = lm_logits(params["embed"], x, cfg.tie_embeddings)
-    return EncDecState(new_kv, state.cross_k, state.cross_v,
-                       index + 1, logits)

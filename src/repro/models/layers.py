@@ -1,26 +1,22 @@
 """Shared layers and the declarative parameter-table mechanism.
 
 Every block declares its parameters once as ``name -> ParamDef(shape,
-logical_axes, init)``; both ``init_params`` (values) and ``param_specs``
-(logical sharding axes, consumed by repro.distributed.sharding) derive from
-the same table, so they cannot drift.
+init)``, and ``init_table`` draws them in sorted name order.
 """
 
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Callable, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 @dataclass(frozen=True)
 class ParamDef:
     shape: tuple
-    axes: tuple                       # logical axis names, len == len(shape)
     init: str = "normal"              # normal | zeros | ones | s4d_real
                                       # | dt_bias
     scale: Optional[float] = None     # stddev override
@@ -65,10 +61,6 @@ def dt_bias(key: jax.Array, shape: tuple) -> jax.Array:
                  + lo)
     dt = jnp.maximum(dt, DT_FLOOR)
     return dt + jnp.log(-jnp.expm1(-dt))
-
-
-def table_specs(table: dict[str, ParamDef]) -> dict[str, tuple]:
-    return {name: pd.axes for name, pd in table.items()}
 
 
 # --------------------------------------------------------------------------
@@ -139,9 +131,9 @@ def apply_mrope(x: jax.Array, positions3: jax.Array, theta: float,
 
 def mlp_table(d_model: int, d_ff: int) -> dict[str, ParamDef]:
     return {
-        "w_gate": ParamDef((d_model, d_ff), ("embed", "mlp")),
-        "w_up": ParamDef((d_model, d_ff), ("embed", "mlp")),
-        "w_down": ParamDef((d_ff, d_model), ("mlp", "embed")),
+        "w_gate": ParamDef((d_model, d_ff)),
+        "w_up": ParamDef((d_model, d_ff)),
+        "w_down": ParamDef((d_ff, d_model)),
     }
 
 
@@ -168,12 +160,11 @@ def _activate(x: jax.Array, act: str) -> jax.Array:
 
 def embed_table(vocab: int, d_model: int, tie: bool) -> dict[str, ParamDef]:
     t = {
-        "embedding": ParamDef((vocab, d_model), ("vocab", "embed"),
-                              scale=1.0),
-        "final_norm": ParamDef((d_model,), ("embed",), init="ones"),
+        "embedding": ParamDef((vocab, d_model), scale=1.0),
+        "final_norm": ParamDef((d_model,), init="ones"),
     }
     if not tie:
-        t["lm_head"] = ParamDef((d_model, vocab), ("embed", "vocab"))
+        t["lm_head"] = ParamDef((d_model, vocab))
     return t
 
 

@@ -9,22 +9,13 @@ batch row, experts run as one batched einsum, results are gathered back and
 gate-combined.  Compute is proportional to *active* experts, matching the
 roofline MODEL_FLOPS = 6·N_active·D accounting.  Overflow tokens are
 dropped (standard GShard semantics; the residual path carries them).
-
-Sharding (see repro.distributed.sharding):
-  expert_sharding="expert": expert dim over `model` (true EP) — the buffers
-      are constrained to P(dp, "model", ...) so GSPMD materialises the
-      token all-to-all at the dispatch/return boundaries.
-  expert_sharding="ffn":    expert weights split over d_ff on `model`
-      (TP inside every expert; no all-to-all; right for few-huge-expert
-      models like grok-1).
+Every expert's weights are held whole: the layer runs on one device.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-from repro.distributed import context as ctx
 
 from .config import ModelConfig
 from .layers import ParamDef, _activate
@@ -33,10 +24,10 @@ from .layers import ParamDef, _activate
 def moe_table(cfg: ModelConfig) -> dict[str, ParamDef]:
     D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts
     return {
-        "router": ParamDef((D, E), ("embed", "expert")),
-        "w_gate": ParamDef((E, D, F), ("expert", "embed", "mlp")),
-        "w_up": ParamDef((E, D, F), ("expert", "embed", "mlp")),
-        "w_down": ParamDef((E, F, D), ("expert", "mlp", "embed")),
+        "router": ParamDef((D, E)),
+        "w_gate": ParamDef((E, D, F)),
+        "w_up": ParamDef((E, D, F)),
+        "w_down": ParamDef((E, F, D)),
     }
 
 
@@ -58,7 +49,6 @@ def _route(cfg: ModelConfig, p: dict, x: jax.Array):
 
     T = S * k
     e_flat = expert_idx.reshape(B, T)                     # (B, T) int32
-    e_flat = ctx.constrain(e_flat, ctx.dp(), None)
     order = jnp.argsort(e_flat, axis=1, stable=True)      # (B, T)
     rank = jnp.argsort(order, axis=1)                     # inverse perm
     counts = jax.vmap(lambda e: jnp.zeros((E,), jnp.int32).at[e].add(1))(
@@ -74,8 +64,6 @@ def moe_forward(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     E, k = cfg.num_experts, cfg.num_experts_per_tok
     C = capacity(cfg, S)
     T = S * k
-    ep = cfg.expert_sharding == "expert"
-    e_shard = "model" if ep else None
 
     gate_vals, expert_idx, e_flat, pos = _route(cfg, p, x)
     keep = pos < C                                        # (B, S, k)
@@ -85,24 +73,20 @@ def moe_forward(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     slot = jnp.where(keep, expert_idx * C + pos, 0)       # (B, S, k)
     tok = jnp.broadcast_to(x[:, :, None, :], (B, S, k, D)).reshape(B, T, D)
     tok = tok * keep.reshape(B, T, 1).astype(x.dtype)
-    tok = ctx.constrain(tok, ctx.dp(), None, None)
     buf = jnp.zeros((B, E * C, D), x.dtype)
     buf = jax.vmap(lambda b, s_, t: b.at[s_].add(t))(
         buf, slot.reshape(B, T), tok)
     xe = buf.reshape(B, E, C, D)
-    xe = ctx.constrain(xe, ctx.dp(), e_shard, None, None)
 
-    # expert computation (batched over E; weights sharded EP or TP)
+    # expert computation (batched over E)
     h = jnp.einsum("becd,edf->becf", xe, p["w_gate"].astype(x.dtype))
     u = jnp.einsum("becd,edf->becf", xe, p["w_up"].astype(x.dtype))
     y = _activate(h, cfg.act) * u
     ye = jnp.einsum("becf,efd->becd", y, p["w_down"].astype(x.dtype))
-    ye = ctx.constrain(ye, ctx.dp(), e_shard, None, None)
 
     # gather back and combine with gates
     yflat = ye.reshape(B, E * C, D)
     ytok = jnp.take_along_axis(yflat, slot.reshape(B, T, 1), axis=1)
-    ytok = ctx.constrain(ytok, ctx.dp(), None, None)
     ytok = ytok.reshape(B, S, k, D) * gate_vals[..., None].astype(x.dtype)
     return ytok.sum(axis=2)
 

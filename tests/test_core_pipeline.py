@@ -555,6 +555,28 @@ def test_prefetched_batches_match_unprefetched(bag_path):
     bag2.close()
 
 
+def test_prefetch_iterator():
+    from repro.data.pipeline import PrefetchIterator
+
+    def slow_gen():
+        for i in range(5):
+            time.sleep(0.01)
+            yield i
+    assert list(PrefetchIterator(slow_gen())) == list(range(5))
+
+
+def test_prefetch_propagates_errors():
+    from repro.data.pipeline import PrefetchIterator
+
+    def bad_gen():
+        yield 1
+        raise RuntimeError("boom")
+    it = PrefetchIterator(bad_gen())
+    assert next(it) == 1
+    with pytest.raises(RuntimeError, match="boom"):
+        list(it)
+
+
 def test_prefetch_close_stops_abandoned_reader():
     """A consumer that bails early must be able to stop the reader thread
     even while it is blocked on the full queue (no leaked thread pinning

@@ -13,6 +13,7 @@ import warnings
 import numpy as np
 import pytest
 
+from repro.configs import ALL_ARCHS
 from repro.core import Bag, Message, Scenario, ScenarioSuite
 from repro.core.aggregation import (accumulate_topic_state_arrays,
                                     finalize_topic_state, record_digests_np)
@@ -229,6 +230,31 @@ def test_perception_step_donates_and_is_silent():
     step._step(step.params, kept, jnp.full(8, 1 / 255, jnp.float32),
                jnp.zeros(8, jnp.float32), jnp.full(8, 128, jnp.int32))
     assert not kept.is_deleted()
+
+
+#: every registered decoder-only config: the step feeds embeddings alone
+DECODERS = [a for a in ALL_ARCHS + ["jamba2-3b"]
+            if a != "seamless-m4t-large-v2"]
+
+
+@pytest.mark.parametrize("arch", DECODERS)
+def test_every_decoder_config_runs_the_step(arch):
+    """``PerceptionStep("<arch>-tiny")`` agrees with the model forward over
+    features decoded off the Pallas path."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels.ref import sensor_decode_reference
+    from repro.perception import PerceptionStep, features_to_logits
+
+    step = PerceptionStep(arch + "-tiny", donate=False)
+    batch = frame_to_batch(encode_data(_msgs(8, payload=256, seed=8)))
+    got = np.asarray(step.step_arrays(batch)[0])
+    feats = sensor_decode_reference(*(jnp.asarray(batch[k]) for k in (
+        "payload", "scale", "zero_point", "lengths")))
+    want = np.asarray(jax.jit(lambda p, f: features_to_logits(
+        step.cfg, p, f, step.out_features))(step.params, feats))
+    assert got.shape == (8, step.out_features) and np.isfinite(got).all()
+    assert np.abs(got - want).max() < 1e-5 * np.abs(want).max()
 
 
 # -- Scenario integration ----------------------------------------------------

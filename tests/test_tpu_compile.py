@@ -134,7 +134,7 @@ def test_full_width_perception_step_fits_one_v5e(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES, used
-    # the bf16 params are most of it: 4.4 B of them at 2 bytes each
+    # the bf16 params are most of it: 4.0 B of them at 2 bytes each
     n_params = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
     assert 4.0e9 < n_params < 4.5e9
 
