@@ -46,6 +46,14 @@ _FILL = obs_metrics.scope("bag_cache")
 _RAW_CHUNKS = _FILL.counter("raw_chunks")
 _DECODED_CHUNKS = _FILL.counter("decoded_chunks")
 
+#: how :func:`iter_time_ordered` let each message out: ``watermark`` once
+#: the index proved no unread chunk holds an earlier one, ``window`` by the
+#: heap-window cap, ``drained`` after the selection's last chunk
+_ORDER = obs_metrics.scope("replay_order")
+_BY_WATERMARK = _ORDER.counter("watermark")
+_BY_WINDOW = _ORDER.counter("window")
+_DRAINED = _ORDER.counter("drained")
+
 
 @dataclass(frozen=True)
 class Message:
@@ -547,18 +555,34 @@ class Bag:
                       end: Optional[int] = None,
                       chunk_range: Optional[tuple[int, int]] = None,
                       ) -> Iterator[Message]:
-        """Time-ordered replay.  ``chunk_range=(lo, hi)`` restricts to a chunk
-        slice — this is the partitioning handle the scheduler uses."""
+        """Replay in chunk order, then record order (globally time-ordered
+        replay is :func:`iter_time_ordered`).  ``chunk_range=(lo, hi)``
+        restricts to a chunk slice — this is the partitioning handle the
+        scheduler uses."""
+        for _, msgs in self._read_chunks(topics, start, end, chunk_range):
+            yield from msgs
+
+    def _read_chunks(self, topics: Optional[Sequence[str]],
+                     start: Optional[int], end: Optional[int],
+                     chunk_range: Optional[tuple[int, int]],
+                     ) -> list[tuple[ChunkInfo, Iterator[Message]]]:
+        """The chunks :meth:`read_messages` selects, in selection order,
+        each with an iterator of its kept messages in record order; a
+        chunk is read when its iterator is first advanced."""
         want = self._topic_ids(topics)
+        return [(self._chunks[i], self._chunk_messages(i, want, start, end))
+                for i in self._selected_chunks(want, start, end, chunk_range)]
+
+    def _chunk_messages(self, i: int, want: Optional[set[int]],
+                        start: Optional[int], end: Optional[int],
+                        ) -> Iterator[Message]:
+        payload, record_count = self._cf.read_chunk(self._chunks[i].offset)
         names = self._topic_names
-        for i in self._selected_chunks(want, start, end, chunk_range):
-            payload, record_count = self._cf.read_chunk(self._chunks[i].offset)
-            for tid, ts, pos, nxt in self._kept_records(
-                    payload, record_count, want, start, end):
-                # bytes() copies a memory image's view once; a disk
-                # payload's slice is already bytes, and passes as is
-                yield Message(names[tid], ts,
-                              bytes(payload[pos + _REC.size:nxt]))
+        for tid, ts, pos, nxt in self._kept_records(
+                payload, record_count, want, start, end):
+            # bytes() copies a memory image's view once; a disk payload's
+            # slice is already bytes, and passes as is
+            yield Message(names[tid], ts, bytes(payload[pos + _REC.size:nxt]))
 
     def selection_image(self, topics: Optional[Sequence[str]] = None,
                         start: Optional[int] = None,
@@ -666,21 +690,44 @@ def iter_time_ordered(bag: Bag, topics: Optional[Sequence[str]] = None,
 
     Bag chunks are time-ordered per chunk but may interleave across chunk
     boundaries (e.g. jittered multi-topic writes); a merge-sort over a
-    small heap window restores global order without materialising the
-    selection.  This is the ordering contract ``RosPlay`` publishes with
-    and :func:`merge_bags` merges with.
+    heap of at most ``window`` messages restores global order.  The bag
+    index bounds what is still unread: after each chunk, every heap entry
+    at or below the least ``t_min`` of the selected chunks not yet read
+    is yielded, since nothing unread can sort before it (an unread message
+    with an equal timestamp comes later in the selection, and ties keep
+    selection order), so a time-ordered bag streams out chunk by chunk.
+    The sequence is the plain heap window's, message for message; the
+    ``replay_order`` metrics scope counts the messages each rule let out.
+    This is the ordering contract ``RosPlay`` publishes with and
+    :func:`merge_bags` merges with.
     """
-    it = bag.read_messages(topics=topics, start=start, end=end,
-                           chunk_range=chunk_range)
+    chunks = bag._read_chunks(topics, start, end, chunk_range)
+    # marks[k]: the least t_min of the chunks after chunk k (None after the
+    # last), below which no unread message can carry a timestamp
+    marks: list[Optional[int]] = [None] * len(chunks)
+    for k in range(len(chunks) - 1, 0, -1):
+        t = chunks[k][0].t_min
+        marks[k - 1] = t if marks[k] is None else min(marks[k], t)
     heap: list[tuple[int, int, Message]] = []
-    seq = 0
-    for msg in it:
-        heapq.heappush(heap, (msg.timestamp, seq, msg))
-        seq += 1
-        if len(heap) > window:
+    seq = by_mark = by_window = drained = 0
+    try:
+        for (_, msgs), mark in zip(chunks, marks):
+            for msg in msgs:
+                heapq.heappush(heap, (msg.timestamp, seq, msg))
+                seq += 1
+                if len(heap) > window:
+                    by_window += 1
+                    yield heapq.heappop(heap)[2]
+            while heap and mark is not None and heap[0][0] <= mark:
+                by_mark += 1
+                yield heapq.heappop(heap)[2]
+        while heap:
+            drained += 1
             yield heapq.heappop(heap)[2]
-    while heap:
-        yield heapq.heappop(heap)[2]
+    finally:
+        _BY_WATERMARK.inc(by_mark)
+        _BY_WINDOW.inc(by_window)
+        _DRAINED.inc(drained)
 
 
 def bag_content_digest(source: "Bag | bytes | str") -> str:

@@ -697,8 +697,9 @@ class RosPlay:
     def _time_ordered(self) -> Iterable[Message]:
         """Bag chunks are time-ordered per-chunk but may interleave across
         topic boundaries; :func:`repro.core.bag.iter_time_ordered` merge-sorts
-        on a small heap window to keep global order without materialising
-        the partition."""
+        them on a heap window and yields each message as soon as the bag
+        index shows that no unread chunk holds an earlier one, so the
+        first batch waits for its own chunks, not for the partition."""
         return iter_time_ordered(self._bag, topics=self._topics,
                                  chunk_range=self._chunk_range,
                                  start=self._start, end=self._end)

@@ -2,9 +2,14 @@
 platform rests on: replay == record); hypothesis round-trips live in
 test_property_based.py."""
 
+import heapq
+import random
+
 import pytest
 
-from repro.core import Bag, MemoryChunkedFile, partition_bag
+from repro.core import Bag, MemoryChunkedFile, iter_time_ordered, partition_bag
+from repro.data.pipeline import iter_message_batches
+from repro.obs import metrics as obs_metrics
 
 
 def _write(bag, msgs):
@@ -100,3 +105,98 @@ class TestPartitioning:
                       for pr in parts)
             assert tot == 1000
 
+
+
+def _heap_window_order(bag, topics=None, start=None, end=None,
+                       chunk_range=None, window=4096):
+    """``iter_time_ordered`` before the index watermark, kept as the oracle:
+    push every message of the selection, pop once the heap exceeds
+    ``window``, drain at the end."""
+    heap = []
+    for seq, msg in enumerate(bag.read_messages(
+            topics=topics, start=start, end=end, chunk_range=chunk_range)):
+        heapq.heappush(heap, (msg.timestamp, seq, msg))
+        if len(heap) > window:
+            yield heapq.heappop(heap)[2]
+    while heap:
+        yield heapq.heappop(heap)[2]
+
+
+def _random_bag(rng):
+    """A memory bag whose chunks hold one record to many, with jittered
+    multi-topic timestamps, equal timestamps across chunks, and now and
+    then a record far behind its neighbours (disorder past small
+    windows)."""
+    b = Bag.open_write(backend="memory",
+                       chunk_bytes=rng.choice([1, 64, 256, 1024, 8192]))
+    tick = rng.choice([1, 7, 50])
+    for i in range(rng.randint(0, 300)):
+        ts = i * 10 + rng.randint(-30, 30)
+        if rng.random() < 0.05:
+            ts -= rng.randint(100, 2000)
+        b.write(rng.choice(["/a", "/b", "/c"]), max(0, ts // tick * tick),
+                i.to_bytes(2, "little") * rng.randint(1, 40))
+    b.close()
+    return Bag.open_read(backend="memory", image=b.chunked_file.image())
+
+
+def _random_selection(rng, bag):
+    sel = {}
+    if rng.random() < 0.4:
+        sel["topics"] = rng.choice([["/a"], ["/b", "/c"], ["/nope"], []])
+    if rng.random() < 0.4:
+        sel["start"] = rng.randint(-50, 2000)
+    if rng.random() < 0.4:
+        sel["end"] = rng.randint(0, 3200)
+    if rng.random() < 0.4 and bag.num_chunks:
+        lo = rng.randint(0, bag.num_chunks)
+        sel["chunk_range"] = (lo, rng.randint(lo, bag.num_chunks))
+    return sel
+
+
+@pytest.mark.parametrize("seed", range(4))
+@pytest.mark.parametrize("window", [1, 2, 3, 8, 4096])
+def test_time_ordered_matches_heap_window(window, seed):
+    """The index watermark yields the plain heap window's sequence, message
+    for message, on every bag and selection and at every window: also
+    where disorder overflows the window."""
+    rng = random.Random(window * 1000 + seed)
+    for _ in range(6):
+        bag = _random_bag(rng)
+        for _ in range(6):
+            sel = _random_selection(rng, bag)
+            want = [(m.topic, m.timestamp, m.data)
+                    for m in _heap_window_order(bag, window=window, **sel)]
+            got = [(m.topic, m.timestamp, m.data)
+                   for m in iter_time_ordered(bag, window=window, **sel)]
+            assert got == want, sel
+
+
+def test_time_ordered_first_batch_reads_its_own_chunks():
+    """A time-ordered bag's first batch of 16 waits for the chunks that hold
+    it, not for the whole selection; the index watermark lets out all but
+    the last chunk's records."""
+    b = Bag.open_write(backend="memory", chunk_bytes=200)
+    for i in range(400):
+        b.write(f"/t{i % 2}", i * 10, bytes([i % 256]) * 100)
+    b.close()
+    bag = Bag.open_read(backend="memory", image=b.chunked_file.image())
+    assert bag.num_chunks == 200
+    reads = []
+    read_chunk = bag.chunked_file.read_chunk
+
+    def counted(offset):
+        reads.append(offset)
+        return read_chunk(offset)
+
+    bag.chunked_file.read_chunk = counted
+    before = obs_metrics.snapshot()["replay_order"]
+    batches = iter_message_batches(iter_time_ordered(bag), batch_size=16)
+    first = next(batches)
+    assert [m.timestamp for m in first] == [i * 10 for i in range(16)]
+    assert len(reads) <= 9
+    rest = [m for batch in batches for m in batch]
+    assert len(first) + len(rest) == 400 and len(reads) == 200
+    after = obs_metrics.snapshot()["replay_order"]
+    split = {k: after[k] - before[k] for k in before}
+    assert split == {"watermark": 398, "window": 0, "drained": 2}

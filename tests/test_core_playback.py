@@ -95,18 +95,23 @@ def test_distributed_simulation_end_to_end(tmp_path):
         assert rep.open_output_bag().num_messages == 600
 
         log = str(tmp_path / f"verdicts-{cache}.jsonl")
-        before = obs_metrics.snapshot()["bag_cache"]
+        before = obs_metrics.snapshot()
         verdicts = ScenarioSuite(
             [dataclasses.replace(sc, use_memory_cache=cache,
                                  golden_bag_path=goldens[sc.name])
              for sc in filtered], num_workers=4).run(verdict_log=log)
         with open(log + ".manifest.json") as f:
-            counts = json.load(f)["metrics"]["bag_cache"]
-        filled = {k: counts[k] - before[k] for k in before}
+            counts = json.load(f)["metrics"]
+        filled, ordered = ({k: counts[scope][k] - before[scope][k]
+                            for k in before[scope]}
+                           for scope in ("bag_cache", "replay_order"))
         # the cache copies the window's inner chunks raw and decodes the
         # cut ones; without the cache nothing is filled
         assert (filled["raw_chunks"] > 0 and filled["decoded_chunks"] > 0) \
             if cache else filled == {"raw_chunks": 0, "decoded_chunks": 0}
+        # a time-ordered bag replays by the index watermark: from the cache
+        # or the disk, no message waits for the heap window's cap
+        assert ordered["window"] == 0 and ordered["watermark"] > 0
         seen[cache] = {
             name: (v.status, [str(d) for d in v.diffs],
                    {t: m.checksum for t, m in v.metrics.items()},
